@@ -264,4 +264,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.core import compat
+
+    compat.setup_compile_cache()  # the CLI only, never on import
     main()

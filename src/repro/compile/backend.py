@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from repro.compile.schedule import Schedule, verify_schedule
 from repro.core import bayesnet as bnet
+from repro.core import compat
 from repro.core import mrf as mrf_mod
 from repro.core.graphs import GridMRF
 from repro.core.interp import build_exp_weight_lut
@@ -286,7 +287,7 @@ def bn_run_clamped(
     the fused path stays bit-identical to the eager engine."""
     if fused:
         check_fused_sampler(sampler)
-    interpret = jax.default_backend() != "tpu"
+    interpret = compat.pallas_interpret()
     # host-level kernel entry span only: the rounds themselves run inside
     # jit/fori_loop where the tracer must never be called
     with tracer.span(
@@ -430,7 +431,7 @@ def run_mrf_schedule(
         )
     if pin_mask is None and ex.pinned:
         pin_mask, pin_vals = pin_arrays(ex.mrf, ex.pinned)
-    interpret = jax.default_backend() != "tpu"
+    interpret = compat.pallas_interpret()
     # host-level kernel entry span only (see bn_run_clamped)
     with tracer.span(
         "mrf_rounds", cat="kernel", sampler=sampler, fused=fused,
@@ -508,7 +509,6 @@ def _check_mesh(program, mesh=None):
         w = next(d for d in range(min(n_dev, h), 0, -1) if h % d == 0)
     else:
         w = min(2, n_dev)
-    from repro.core import compat
 
     return compat.make_mesh((1, w), ("data", "model"))
 

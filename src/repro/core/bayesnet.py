@@ -271,7 +271,12 @@ def group_log_conditionals(
     addr = g.base[None, :, :, None] + jnp.sum(
         g.stride[None, ..., None] * val_or_v, axis=-2
     )  # (B, n_c, F, V)
-    logp = jnp.sum(cbn.log_flat[addr], axis=-2)  # (B, n_c, V)
+    terms = cbn.log_flat[addr]
+    # factor by factor, left to right: a reduction's order is the compiler's
+    # choice, and the fused kernel (kernels/bn_gibbs.py) adds in this order
+    logp = terms[:, :, 0]
+    for f in range(1, terms.shape[2]):
+        logp = logp + terms[:, :, f]  # (B, n_c, V)
     return jnp.where(v_range < g.cards[None, :, None], logp, NEG_INF)
 
 

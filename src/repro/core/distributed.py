@@ -340,39 +340,27 @@ def _quality_spec(chain_axis: str | None, site_axis: str | None):
     )
 
 
-def mrf_fused_sharded(
+def mrf_fused_sharded_program(
     mrf: GridMRF,
-    evidence: jax.Array,  # (H, W) int32
-    key: jax.Array | None,
     mesh: jax.sharding.Mesh,
     *,
     n_chains: int,
     n_iters: int,
     parities: tuple[int, ...],
-    carry: mrf_mod.MRFChainState | None = None,
-    return_state: bool = False,
-    diag_total=None,
-    diag_batch: int = diag_accum.DEFAULT_BATCH_LEN,
+    with_quality: bool = False,
     chain_axis: str = "data",
     grid_axis: str = "model",
     interpret: bool | None = None,
-    profile_sig: str | None = None,
 ):
-    """The fused MRF schedule rounds inside one `shard_map` body: per shard,
-    one `pallas_call` half-step over the local row slab per round, with the
-    halo rows exchanged via `lax.ppermute` (the `ppermute_halo` mechanism)
-    between rounds — comm and compute in a single scanned body instead of
-    separate engine ops.
-
-    Bit-exact with `compile/backend.run_mrf_schedule(fused=True)`: the init,
-    key-split structure, and per-site word streams are identical (full-grid
-    streams sliced to the slab), so carries cross the vmap<->sharded route
-    boundary freely and sliced serving rides the sharded route.  The
-    `MRFChainState` carry shards its labels (and `QualityAccum` site-moment
-    leaves) over `grid_axis`; pins never route here (`executor.route`
-    excludes them)."""
+    """The jitted one-`shard_map`-body program `mrf_fused_sharded` runs:
+    `(labels (B, H, W), key, quality | None, evidence (H, W)) -> (labels,
+    key, quality)`, labels sharded (chain_axis, grid_axis) and evidence
+    rows over grid_axis.  Per shard and round, one `pallas_call` half-step
+    over the local row slab, with the halo rows exchanged via
+    `lax.ppermute` (the `ppermute_halo` mechanism) between rounds — comm
+    and compute in a single scanned body instead of separate engine ops."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = compat.pallas_interpret()
     exp_table, exp_spec = build_exp_weight_lut()
     n_grid = mesh.shape[grid_axis]
     n_chain_dev = mesh.shape[chain_axis]
@@ -386,21 +374,7 @@ def mrf_fused_sharded(
         )
     h_loc = mrf.height // n_grid
     b_loc = n_chains // n_chain_dev
-
-    if carry is None:
-        labels, key = mrf_mod.init_labels(mrf, key, n_chains)
-        quality = None
-        if diag_total is not None:
-            quality = diag_accum.make_accum(
-                n_chains, mrf.height * mrf.width, mrf.n_labels,
-                jnp.asarray(diag_total, jnp.int32), diag_batch,
-            )
-    else:
-        labels, key, quality = carry.labels, carry.key, carry.quality
-
-    qspec = None
-    if quality is not None:
-        qspec = _quality_spec(chain_axis, grid_axis)
+    qspec = _quality_spec(chain_axis, grid_axis) if with_quality else None
     lab_spec = P(chain_axis, grid_axis, None)
 
     def body(labels, key, quality, ev_loc):
@@ -431,14 +405,59 @@ def mrf_fused_sharded(
 
         return jax.lax.fori_loop(0, n_iters, it, (labels, key, quality))
 
-    f = compat.shard_map(
+    return jax.jit(compat.shard_map(
         body,
         mesh=mesh,
         in_specs=(lab_spec, P(), qspec, P(grid_axis, None)),
         out_specs=(lab_spec, P(), qspec),
         check_vma=False,
+    ))
+
+
+def mrf_fused_sharded(
+    mrf: GridMRF,
+    evidence: jax.Array,  # (H, W) int32
+    key: jax.Array | None,
+    mesh: jax.sharding.Mesh,
+    *,
+    n_chains: int,
+    n_iters: int,
+    parities: tuple[int, ...],
+    carry: mrf_mod.MRFChainState | None = None,
+    return_state: bool = False,
+    diag_total=None,
+    diag_batch: int = diag_accum.DEFAULT_BATCH_LEN,
+    chain_axis: str = "data",
+    grid_axis: str = "model",
+    interpret: bool | None = None,
+    profile_sig: str | None = None,
+):
+    """The fused MRF schedule rounds inside one `shard_map` body
+    (`mrf_fused_sharded_program`).
+
+    Bit-exact with `compile/backend.run_mrf_schedule(fused=True)`: the init,
+    key-split structure, and per-site word streams are identical (full-grid
+    streams sliced to the slab), so carries cross the vmap<->sharded route
+    boundary freely and sliced serving rides the sharded route.  The
+    `MRFChainState` carry shards its labels (and `QualityAccum` site-moment
+    leaves) over `grid_axis`; pins never route here (`executor.route`
+    excludes them)."""
+    if carry is None:
+        labels, key = mrf_mod.init_labels(mrf, key, n_chains)
+        quality = None
+        if diag_total is not None:
+            quality = diag_accum.make_accum(
+                n_chains, mrf.height * mrf.width, mrf.n_labels,
+                jnp.asarray(diag_total, jnp.int32), diag_batch,
+            )
+    else:
+        labels, key, quality = carry.labels, carry.key, carry.quality
+
+    jf = mrf_fused_sharded_program(
+        mrf, mesh, n_chains=n_chains, n_iters=n_iters, parities=parities,
+        with_quality=quality is not None, chain_axis=chain_axis,
+        grid_axis=grid_axis, interpret=interpret,
     )
-    jf = jax.jit(f)
     args = (labels, key, quality, evidence)
     _maybe_capture(profile_sig, jf, args, mesh, kind="mrf",
                    model=getattr(mrf, "name", None), sampler="lut_ky",
@@ -460,7 +479,7 @@ class ShardedFusedRounds:
     node's position in its round's *full* group ordering, so every device
     can slice its rows out of the full random-word stream — the key to
     sharded draws being bit-identical to the single-device kernel's.
-    Pad slots carry node id n_nodes (dropped by the one-hot scatter),
+    Pad slots carry node id n_nodes (no lane of the scatter reads them),
     cards 0 (masked to NEG_INF) and word_pos 0 (a real row whose draw is
     discarded)."""
 
@@ -579,7 +598,7 @@ def bn_fused_sharded(
     `BNChainState` carry shards its vals/quality chain leaves over
     `chain_axis`; the histogram is merged exactly (int32 psum)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = compat.pallas_interpret()
     bn_gibbs.check_fused_sampler(sampler)
     groups = cbn.groups if groups is None else groups
     n_dev = mesh.shape[node_axis]
@@ -590,14 +609,10 @@ def bn_fused_sharded(
         )
     b_loc = n_chains // n_chain_dev
     v = cbn.max_card
-    weight_bits = 8 if sampler == "lut_ky" else 15
-    precision = max(16, weight_bits + (v - 1).bit_length() + 1)
-    max_retries = 8
-    total_steps = precision * max_retries
-    n_words = -(-total_steps // 32)
+    weight_bits, precision, total_steps, n_words = bn_gibbs.draw_params(
+        v, sampler
+    )
     sfr = build_sharded_fused_rounds(cbn, groups, n_dev, placement)
-    logf = jnp.reshape(cbn.log_flat, (1, -1))
-    tab = jnp.reshape(cbn.exp_table, (1, -1)).astype(jnp.float32)
     n_rounds = len(sfr.n_c)
 
     if carry is None:
@@ -648,11 +663,10 @@ def bn_fused_sharded(
                 wr = jnp.take(wr, word_pos[r], axis=1)  # (b_loc, C, W)
                 new_vals = bn_gibbs.fused_color_round(
                     vals, nodes[r], cards[r], base[r], stride[r],
-                    scope_var[r], is_self[r], wr, logf, tab,
+                    scope_var[r], is_self[r], wr, cbn.log_flat, cbn.exp_table,
                     sampler=sampler, exp_spec=cbn.exp_spec, v_max=v,
-                    n_words=n_words, weight_bits=weight_bits,
-                    precision=precision, total_steps=total_steps,
-                    interpret=interpret,
+                    weight_bits=weight_bits, precision=precision,
+                    total_steps=total_steps, interpret=interpret,
                 )
                 # disjoint ownership => one int psum merges all updates
                 # (the psum_broadcast mechanism, exact in int32)

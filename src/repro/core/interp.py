@@ -34,6 +34,12 @@ class LUTSpec:
     def x1(self) -> float:
         return self.x0 + self.dx * (self.size - 1)
 
+    @property
+    def inv_dx(self) -> float:
+        """1 / dx: the lerp multiplies by it (as f32) instead of dividing by
+        dx, so XLA and the Pallas kernels round the grid position alike."""
+        return 1.0 / self.dx
+
 
 def build_lut(
     fn: Callable[[np.ndarray], np.ndarray],
@@ -66,7 +72,7 @@ def build_log_lut(size: int = DEFAULT_SIZE, x0: float = 1.0, x1: float = 2.0):
 
 def interp_ref(x: jax.Array, table: jax.Array, spec: LUTSpec) -> jax.Array:
     """Pure-jnp oracle: y = Y[i] + frac * (Y[i+1] - Y[i])   (paper Sec. III-D)."""
-    u = jnp.clip((x - spec.x0) / spec.dx, 0.0, spec.size - 1)
+    u = jnp.clip((x - spec.x0) * spec.inv_dx, 0.0, spec.size - 1)
     idx = jnp.clip(jnp.floor(u), 0, spec.size - 2).astype(jnp.int32)
     frac = u - idx.astype(u.dtype)
     y0 = jnp.take(table, idx)

@@ -50,18 +50,21 @@ class KYState(NamedTuple):
 
 
 def scale_to_fill(m: jax.Array, precision: int = DEFAULT_PRECISION) -> jax.Array:
-    """Multiply integer weights by floor(2^W / S).
+    """Multiply integer weights by floor((2^W - 1) / S).
 
     Scaling all weights by the same positive integer leaves the distribution
     unchanged but pushes the sum toward 2^W, shrinking the rejection bin
-    (rej = 2^W - k*S < S).  This is the software analogue of the paper's
-    observation that low-rejection configurations sample fastest.
+    (rej = 2^W - k*S <= S).  This is the software analogue of the paper's
+    observation that low-rejection configurations sample fastest.  The
+    scaled sum stays below 2^W, so no single bin reaches 2^W: such a weight
+    needs bit W, which the W-level DDG tree does not have (a lone nonzero
+    bin whose weight divides 2^W would otherwise never terminate).
 
-    m: (..., N) int32, sum(m) in [1, 2^W].  Returns scaled weights.
+    m: (..., N) int32, sum(m) in [1, 2^W).  Returns scaled weights.
     """
     s = jnp.sum(m, axis=-1, keepdims=True)
     s = jnp.maximum(s, 1)
-    k = (1 << precision) // s
+    k = ((1 << precision) - 1) // s
     k = jnp.maximum(k, 1)
     return m * k
 

@@ -23,12 +23,17 @@ DEFAULT_BLOCK_M = 256
 
 
 def interp_eval(
-    x: jax.Array, tab_row, x0: float, dx: float, size: int
+    x: jax.Array, tab_row, x0: float, inv_dx: float, size: int
 ) -> jax.Array:
     """Fused LUT lerp on an in-VMEM value array; tab_row is a (1, L) ref or
     array whose scalar entries are read per unrolled step (size <= 32).
-    Shared by this kernel and the fused mrf_gibbs kernel."""
-    u = jnp.clip((x - x0) / dx, 0.0, float(size - 1))
+    Shared by this kernel and the fused mrf_gibbs / bn_gibbs kernels.
+
+    The float expression is `core.interp.interp_ref`'s, term for term: the
+    grid position multiplies by the same f32 `inv_dx` constant (a multiply
+    rounds identically under Mosaic and XLA, a divide need not), which is
+    what keeps the fused kernels bit-exact with the unfused draws on TPU."""
+    u = jnp.clip((x - x0) * inv_dx, 0.0, float(size - 1))
     idx = jnp.minimum(u.astype(jnp.int32), size - 2)
     frac = u - idx.astype(u.dtype)
     y0 = jnp.zeros_like(x)
@@ -40,8 +45,10 @@ def interp_eval(
     return y0 + frac * (y1 - y0)
 
 
-def _interp_kernel(x_ref, tab_ref, y_ref, *, x0: float, dx: float, size: int):
-    y_ref[...] = interp_eval(x_ref[...], tab_ref, x0, dx, size)
+def _interp_kernel(
+    x_ref, tab_ref, y_ref, *, x0: float, inv_dx: float, size: int
+):
+    y_ref[...] = interp_eval(x_ref[...], tab_ref, x0, inv_dx, size)
 
 
 @functools.partial(
@@ -68,7 +75,7 @@ def interp_kernel(
     block_m = min(block_m, m)
     grid = (pl.cdiv(m, block_m),)
     kernel = functools.partial(
-        _interp_kernel, x0=spec.x0, dx=spec.dx, size=spec.size
+        _interp_kernel, x0=spec.x0, inv_dx=spec.inv_dx, size=spec.size
     )
     vmem = compat.pallas_vmem()
     return pl.pallas_call(

@@ -6,22 +6,25 @@ per-cycle datapath (Fig. 5) maps onto the TPU as:
 
   hardware AIA                          this kernel
   ------------------------------------  -------------------------------------
-  32-bin distribution in private RF     (block_b, 128) int32 weights in VMEM
-  per-cycle DDG column read (SU.B)      on-the-fly shift of the weight lanes
-  parallel-prefix adder over bins       cumsum via lower-triangular MXU matmul
+  32-bin distribution in private RF     one int32 plane per bin in VMEM
+  per-cycle DDG column read (SU.B)      on-the-fly shift of every bin plane
+  parallel-prefix adder over bins       running sum over the unrolled planes
   LFSR random bit                       packed jax.random words in VMEM
-  FSM rejection-restart                 masked lane-wise restart, early-exit
+  FSM rejection-restart                 masked per-site restart, early-exit
                                         while_loop => O(H) expected levels
 
-Batching over VPU sublanes replaces AIA's 16 parallel cores: all same-color
+Batching over VPU lanes replaces AIA's 16 parallel cores: all same-color
 RVs / serving requests walk their DDG trees in lock-step, each with private
 state, exactly like the paper's asynchronous cores between barriers.
 
-Block layout: bins live on the 128-wide lane axis (N + rejection bin <= 128;
-wider distributions are handled hierarchically by token_sampler.py), batch on
-the sublane axis.  The whole working set (weights, bit words, walk state) for
-a block is VMEM-resident; the distribution is produced, walked and discarded
-without an HBM round-trip — the paper's private-RF locality argument.
+Layout: the walk works on *planes* — one 2-D array per bin, sites laid out
+lane-dense — so the same `ddg_walk` serves this kernel (batch on lanes, one
+bin per sublane row of the transposed weights), the MRF kernel ((rows, W)
+tiles) and the BN kernel ((chains, nodes) tiles).  Bins are unrolled, so N
++ rejection bin <= 128 keeps the loop short (wider distributions go through
+token_sampler.py).  The whole working set is VMEM-resident; the distribution
+is produced, walked and discarded without an HBM round-trip — the paper's
+private-RF locality argument.
 """
 
 from __future__ import annotations
@@ -39,114 +42,122 @@ LANES = 128
 DEFAULT_BLOCK_B = 256
 
 
-def _cumsum_lanes(x: jax.Array) -> jax.Array:
-    """Inclusive cumsum along the last (lane) axis via triangular matmul.
-
-    TPU Pallas has no native 1-pass lane scan; an (N, N) lower-triangular
-    int32 matmul on the MXU is the idiomatic replacement (the paper uses a
-    parallel-prefix adder for the same reduction over its 32 bins).
-    """
-    n = x.shape[-1]
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-           <= jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)).astype(jnp.int32)
-    return jax.lax.dot_general(
-        x, tri, (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+def zeros_plane(shape, dtype=jnp.int32) -> jax.Array:
+    """A 2-D zero array that Mosaic lays out like data (varying along both
+    axes).  A splat constant or a one-axis iota gets a replicated layout,
+    and a loop carry initialized with one cannot be relaid into the
+    layout its loop body produces."""
+    z = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+         + jax.lax.broadcasted_iota(jnp.int32, shape, 1)) >> 31
+    return z.astype(dtype)
 
 
-def preprocess_lanes(m: jax.Array, n_bins: int, precision: int) -> jax.Array:
-    """In-VMEM preprocessing on lane-padded weights (b, LANES): clamp ->
-    scale-to-fill -> write the rejection bin into lane `n_bins` (Eqns. 8-9)."""
-    b = m.shape[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (b, LANES), 1)
-    m = jnp.maximum(m, 0)
-    m = jnp.where(lane < n_bins, m, 0)
-    s = jnp.sum(m, axis=-1, keepdims=True)
-    m = jnp.where(s > 0, m, jnp.where(lane < n_bins, 1, 0))
-    s = jnp.sum(m, axis=-1, keepdims=True)
-    k = jnp.maximum((1 << precision) // s, 1)
-    m = m * k
-    rej = (1 << precision) - jnp.sum(m, axis=-1, keepdims=True)
-    return jnp.where(lane == n_bins, rej, m)
+def preprocess_planes(w: list, precision: int) -> list:
+    """Clamp -> scale-to-fill -> rejection bin (Eqns. 8-9) on per-bin planes.
+
+    `w` holds one int32 plane per bin, all of one shape (any sites layout);
+    returns len(w) + 1 planes summing to 2^precision, the rejection bin
+    last.  Integer-only, so any summation order is exact."""
+    m = [jnp.maximum(x, 0) for x in w]
+    empty = _plane_sum(m) <= 0
+    m = [jnp.where(empty, 1, x) for x in m]  # all-zero row -> uniform
+    # (2^W - 1) // S, as core.ky.scale_to_fill: no bin may reach 2^W
+    k = jnp.maximum(((1 << precision) - 1) // _plane_sum(m), 1)
+    m = [x * k for x in m]
+    return m + [(1 << precision) - _plane_sum(m)]
+
+
+def _plane_sum(planes: list) -> jax.Array:
+    acc = planes[0]
+    for x in planes[1:]:
+        acc = acc + x
+    return acc
 
 
 def ddg_walk(
-    m_ext: jax.Array, words: jax.Array, *, n_bins: int, precision: int,
+    m_ext: list, words: list, *, n_bins: int, precision: int,
     total_steps: int,
 ):
-    """Early-exit batched DDG walk on prepared lane-padded weights.
+    """Early-exit batched DDG walk on per-bin planes.
 
-    m_ext (b, LANES) int32 summing to 2^precision (rejection in lane n_bins),
-    words (b, n_words) uint32.  Returns (labels, bits, rejs, done), all
-    (b, 1); labels is -1 where the bit budget ran out (caller applies the
-    argmax fallback).  Runs inside Pallas kernel bodies and plain jit alike.
-    """
-    b = m_ext.shape[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (b, LANES), 1)
-    zi = jnp.zeros((b, 1), jnp.int32)
+    `m_ext` is `preprocess_planes`' output (n_bins + 1 int32 planes, the
+    rejection bin last) and `words` the n_words uint32 planes of packed
+    random bits, all of one 2-D sites shape.  Each site walks its own tree:
+    one bit per level, the running sum over the bin planes plays the
+    parallel-prefix adder, and the first bin whose prefix exceeds the
+    distance terminates.  Returns (labels, bits, rejs, done) planes; labels
+    is -1 where the bit budget ran out (`argmax_fallback` fills those).
 
+    Sites stay lane-dense and bins are unrolled, so the walk lowers with
+    Mosaic: no lane-axis dynamic slice, no (b, 1) state, no matmul."""
+    zi = zeros_plane(m_ext[0].shape)
+    one = jnp.asarray(1, words[0].dtype)
+
+    # `done` rides the loop as an int32 0/1 plane: Mosaic cannot carry a
+    # vector mask through a while loop
     def cond(carry):
         t, d, level, label, done, bits, rejs = carry
-        return (t < total_steps) & jnp.any(~done)
+        return (t < total_steps) & jnp.any(done == 0)
 
     def body(carry):
         t, d, level, label, done, bits, rejs = carry
-        word = jax.lax.dynamic_slice_in_dim(words, t // 32, 1, axis=1)
-        shift = jnp.asarray(t % 32).astype(words.dtype)
-        one = jnp.asarray(1, words.dtype)
-        bit = (jnp.right_shift(word, shift) & one).astype(jnp.int32)  # (b, 1)
-        active = ~done
+        word = words[0]
+        for k in range(1, len(words)):  # static select: word t // 32
+            word = jnp.where(t // 32 == k, words[k], word)
+        shift = jnp.asarray(t % 32).astype(word.dtype)
+        bit = (jnp.right_shift(word, shift) & one).astype(jnp.int32)
+        active = done == 0
         d = jnp.where(active, 2 * d + bit, d)
-        col = (m_ext >> (precision - 1 - level)) & 1  # (b, LANES)
-        c = _cumsum_lanes(col)
-        total = c[:, LANES - 1:LANES]
-        hit = c > d
-        # first hit lane = min lane index among hits
-        idx = jnp.min(jnp.where(hit, lane, LANES), axis=-1, keepdims=True)
-        terminated = active & (total > d)
+        col_shift = (precision - 1) - level
+        c, idx = zi, zi
+        for m in m_ext:
+            c = c + ((m >> col_shift) & 1)
+            idx = idx + (c <= d).astype(jnp.int32)  # first bin with c > d
+        terminated = active & (c > d)
         is_rej = idx >= n_bins
         accept = terminated & ~is_rej
         reject = terminated & is_rej
         cont = active & ~terminated
         return (
             t + 1,
-            jnp.where(reject, 0, jnp.where(cont, d - total, d)),
+            jnp.where(reject, 0, jnp.where(cont, d - c, d)),
             jnp.where(reject, 0, jnp.where(cont, level + 1, level)),
             jnp.where(accept, idx, label),
-            done | accept,
+            jnp.where(accept, 1, done),
             bits + active.astype(jnp.int32),
             rejs + reject.astype(jnp.int32),
         )
 
     t0 = jnp.zeros((), jnp.int32)
-    carry = (t0, zi, zi, zi - 1, jnp.zeros((b, 1), bool), zi, zi)
+    carry = (t0, zi, zi, zi - 1, zi, zi, zi)
     _, d, level, label, done, bits, rejs = jax.lax.while_loop(cond, body, carry)
+    done = done != 0
     return label, bits, rejs, done
 
 
-def argmax_fallback(
-    m: jax.Array, labels: jax.Array, done: jax.Array, n_bins: int
-) -> jax.Array:
-    """Fallback for the (<2^-max_retries) bit-exhaustion case: argmax weight."""
-    b = m.shape[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (b, LANES), 1)
-    m = jnp.where(lane < n_bins, m, -1)
-    mx = jnp.max(m, axis=-1, keepdims=True)
-    amax = jnp.min(jnp.where(m == mx, lane, LANES), axis=-1, keepdims=True)
+def argmax_fallback(w: list, labels: jax.Array, done: jax.Array) -> jax.Array:
+    """Fallback for the (<2^-max_retries) bit-exhaustion case: the first bin
+    of largest raw weight (`jnp.argmax` semantics), on per-bin planes."""
+    best, amax = w[0], jnp.zeros(w[0].shape, jnp.int32)
+    for v in range(1, len(w)):
+        better = w[v] > best
+        best = jnp.where(better, w[v], best)
+        amax = jnp.where(better, v, amax)
     return jnp.where(done, labels, amax)
 
 
 def _ky_kernel(
     w_ref, words_ref, labels_ref, bits_ref, rej_ref, fb_ref,
-    *, n_bins: int, precision: int, total_steps: int,
+    *, n_bins: int, n_words: int, precision: int, total_steps: int,
 ):
-    m_ext = preprocess_lanes(w_ref[...], n_bins, precision)
+    # bins on sublane rows: row v of the transposed block is bin v's plane
+    w = [w_ref[v:v + 1, :] for v in range(n_bins)]
+    words = [words_ref[k:k + 1, :] for k in range(n_words)]
     label, bits, rejs, done = ddg_walk(
-        m_ext, words_ref[...], n_bins=n_bins, precision=precision,
-        total_steps=total_steps,
+        preprocess_planes(w, precision), words, n_bins=n_bins,
+        precision=precision, total_steps=total_steps,
     )
-    labels_ref[...] = argmax_fallback(w_ref[...], label, done, n_bins)
+    labels_ref[...] = argmax_fallback(w, label, done)
     bits_ref[...] = bits
     rej_ref[...] = rejs
     fb_ref[...] = (~done).astype(jnp.int32)
@@ -186,29 +197,32 @@ def ky_sample_kernel(
         raise ValueError(
             f"not enough random bits: {n_words} words < {total_steps} steps"
         )
-    block_b = min(block_b, b)
+    # the batch sits on lanes: a partial block must be whole lane tiles
+    block_b = b if block_b >= b else min(-(-block_b // LANES) * LANES, b)
     grid = (pl.cdiv(b, block_b),)
 
     kernel = functools.partial(
-        _ky_kernel, n_bins=n_bins, precision=precision, total_steps=total_steps
+        _ky_kernel, n_bins=n_bins, n_words=n_words, precision=precision,
+        total_steps=total_steps,
     )
-    out_shape = [jax.ShapeDtypeStruct((b, 1), jnp.int32)] * 4
-    spec_b = lambda shp: pl.BlockSpec(shp, lambda i: (i, 0),
-                                      memory_space=compat.pallas_vmem())
+    vmem = compat.pallas_vmem()
+    spec_b = lambda rows: pl.BlockSpec((rows, block_b), lambda i: (0, i),
+                                       memory_space=vmem)
+    # batch on lanes, bins / words on sublane rows (transposed by XLA)
     labels, bits, rejs, fb = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[spec_b((block_b, LANES)), spec_b((block_b, n_words))],
-        out_specs=[spec_b((block_b, 1))] * 4,
-        out_shape=out_shape,
+        in_specs=[spec_b(LANES), spec_b(n_words)],
+        out_specs=[spec_b(1)] * 4,
+        out_shape=[jax.ShapeDtypeStruct((1, b), jnp.int32)] * 4,
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(weights, words)
+    )(weights.T, words.T)
     stats = {
-        "bits_used": bits[:, 0],
-        "rejections": rejs[:, 0],
-        "fallback": fb[:, 0].astype(bool),
+        "bits_used": bits[0],
+        "rejections": rejs[0],
+        "fallback": fb[0].astype(bool),
     }
-    return labels[:, 0], stats
+    return labels[0], stats

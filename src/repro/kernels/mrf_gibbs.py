@@ -9,7 +9,7 @@ as a single VMEM-resident pipeline, fusing all four innovations:
      with a `ppermute` — the mesh-neighbor register read, ICI-native;
   2. energy computation (programmable ALU) — Potts smoothness + data cost;
   3. LUT-exp via the interpolation unit (C2) — `interp_eval`, int8 weights;
-  4. rejection-KY draw (C1) — `ddg_walk` over V<=32 lanes per site.
+  4. rejection-KY draw (C1) — `ddg_walk` over one plane per label value.
 
 The conditional distribution of every site is produced, sampled and
 discarded inside the tile — zero HBM round-trips for intermediates, the
@@ -28,7 +28,7 @@ from repro.core import ky as ky_core
 from repro.core.interp import LUTSpec
 from repro.kernels.interp_lut import interp_eval
 from repro.kernels.ky_sampler import LANES, argmax_fallback, ddg_walk, \
-    preprocess_lanes
+    preprocess_planes
 
 pl = compat.pallas()
 
@@ -38,7 +38,7 @@ DEFAULT_BLOCK_H = 32
 def _mrf_tile_body(
     lab, up_halo, down_halo, ev, words, tab_ref, gr0,
     *, parity: int, theta: float, h: float, n_labels: int, data_cost: str,
-    x0: float, dx: float, lut_size: int, precision: int, total_steps: int,
+    x0: float, inv_dx: float, lut_size: int, precision: int, total_steps: int,
     block_h: int, width: int,
 ):
     """The fused half-step pipeline on one (block_h, W) tile: energies ->
@@ -53,10 +53,7 @@ def _mrf_tile_body(
     left = jnp.concatenate([neg_col, lab[:, :-1]], axis=1)
     right = jnp.concatenate([lab[:, 1:], neg_col], axis=1)
 
-    s = block_h * width
-
     # --- energies per candidate value, same op order as the ref oracle -----
-    z_cols = []
     e_max = jnp.full((block_h, width), -jnp.inf, jnp.float32)
     energies = []
     for v in range(n_labels):
@@ -72,24 +69,22 @@ def _mrf_tile_body(
         e = theta * cnt + data
         energies.append(e)
         e_max = jnp.maximum(e_max, e)
-    for v in range(n_labels):
-        z_cols.append((energies[v] - e_max).reshape(s, 1))
 
-    # --- C2: LUT-exp -> int8 weights on the (site, value) layout -----------
-    z = jnp.concatenate(z_cols, axis=1)  # (s, V)
-    w = jnp.maximum(jnp.round(interp_eval(z, tab_ref, x0, dx, lut_size)), 0.0)
-    w = w.astype(jnp.int32)
-    pad = jnp.zeros((s, LANES - n_labels), jnp.int32)
-    w = jnp.concatenate([w, pad], axis=1)  # (s, LANES)
+    # --- C2: LUT-exp -> int8 weights, one (block_h, W) plane per value -----
+    w = [
+        jnp.maximum(
+            jnp.round(interp_eval(e - e_max, tab_ref, x0, inv_dx, lut_size)),
+            0.0,
+        ).astype(jnp.int32)
+        for e in energies
+    ]
 
     # --- C1: rejection-KY walk over all sites of the tile ------------------
-    words = words.reshape(s, -1)
-    m_ext = preprocess_lanes(w, n_labels, precision)
     label, bits, rejs, done = ddg_walk(
-        m_ext, words, n_bins=n_labels, precision=precision,
-        total_steps=total_steps,
+        preprocess_planes(w, precision), words, n_bins=n_labels,
+        precision=precision, total_steps=total_steps,
     )
-    new = argmax_fallback(w, label, done, n_labels).reshape(block_h, width)
+    new = argmax_fallback(w, label, done)
 
     # --- checkerboard scatter (only this color updates) --------------------
     gr = gr0 + jax.lax.broadcasted_iota(jnp.int32, (block_h, width), 0)
@@ -98,10 +93,15 @@ def _mrf_tile_body(
     return jnp.where(mask, new, lab)
 
 
+def _planes(ref) -> list:
+    """The (k, rows, W) block as a list of k (rows, W) planes."""
+    return [ref[k] for k in range(ref.shape[0])]
+
+
 def _mrf_kernel(
     lab_prev_ref, lab_ref, lab_next_ref, ev_ref, words_ref, tab_ref, out_ref,
     *, parity: int, theta: float, h: float, n_labels: int, data_cost: str,
-    x0: float, dx: float, lut_size: int, precision: int, total_steps: int,
+    x0: float, inv_dx: float, lut_size: int, precision: int, total_steps: int,
     block_h: int, n_blocks: int, width: int,
 ):
     i = pl.program_id(0)
@@ -112,9 +112,9 @@ def _mrf_kernel(
     up_halo = jnp.where(i > 0, lab_prev_ref[block_h - 1 : block_h, :], neg)
     down_halo = jnp.where(i < n_blocks - 1, lab_next_ref[0:1, :], neg)
     out_ref[...] = _mrf_tile_body(
-        lab, up_halo, down_halo, ev_ref[...], words_ref[...], tab_ref,
+        lab, up_halo, down_halo, ev_ref[...], _planes(words_ref), tab_ref,
         i * block_h, parity=parity, theta=theta, h=h, n_labels=n_labels,
-        data_cost=data_cost, x0=x0, dx=dx, lut_size=lut_size,
+        data_cost=data_cost, x0=x0, inv_dx=inv_dx, lut_size=lut_size,
         precision=precision, total_steps=total_steps, block_h=block_h,
         width=width,
     )
@@ -124,7 +124,7 @@ def _mrf_halo_kernel(
     off_ref, up_ref, down_ref, lab_prev_ref, lab_ref, lab_next_ref, ev_ref,
     words_ref, tab_ref, out_ref,
     *, parity: int, theta: float, h: float, n_labels: int, data_cost: str,
-    x0: float, dx: float, lut_size: int, precision: int, total_steps: int,
+    x0: float, inv_dx: float, lut_size: int, precision: int, total_steps: int,
     block_h: int, n_blocks: int, width: int,
 ):
     """The sharded-slab variant: the slab's outermost halo rows come in as
@@ -141,12 +141,25 @@ def _mrf_halo_kernel(
         i < n_blocks - 1, lab_next_ref[0:1, :], down_ref[...]
     )
     out_ref[...] = _mrf_tile_body(
-        lab, up_halo, down_halo, ev_ref[...], words_ref[...], tab_ref,
+        lab, up_halo, down_halo, ev_ref[...], _planes(words_ref), tab_ref,
         off_ref[0, 0] + i * block_h, parity=parity, theta=theta, h=h,
-        n_labels=n_labels, data_cost=data_cost, x0=x0, dx=dx,
+        n_labels=n_labels, data_cost=data_cost, x0=x0, inv_dx=inv_dx,
         lut_size=lut_size, precision=precision, total_steps=total_steps,
         block_h=block_h, width=width,
     )
+
+
+def word_planes(words: jax.Array, width: int) -> jax.Array:
+    """(H, W * n_words) row-major words -> (n_words, H, W) planes, so the
+    kernel reads word k of every site of a tile as one lane-dense plane."""
+    height = words.shape[0]
+    return words.reshape(height, width, -1).transpose(2, 0, 1)
+
+
+def word_planes_spec(n_words: int, block_h: int, width: int, vmem):
+    """Row block i of every word plane."""
+    return pl.BlockSpec((n_words, block_h, width), lambda i: (0, i, 0),
+                        memory_space=vmem)
 
 
 @functools.partial(
@@ -194,7 +207,7 @@ def mrf_half_step_kernel(
 
     kernel = functools.partial(
         _mrf_kernel, parity=parity, theta=theta, h=h, n_labels=n_labels,
-        data_cost=data_cost, x0=spec.x0, dx=spec.dx, lut_size=spec.size,
+        data_cost=data_cost, x0=spec.x0, inv_dx=spec.inv_dx, lut_size=spec.size,
         precision=precision, total_steps=total_steps, block_h=block_h,
         n_blocks=n_blocks, width=width,
     )
@@ -204,7 +217,6 @@ def mrf_half_step_kernel(
     def blk(idx_fn, cols):
         return pl.BlockSpec((block_h, cols), idx_fn, memory_space=vmem)
 
-    n_words_cols = words.shape[1]
     return pl.pallas_call(
         kernel,
         grid=(n_blocks,),
@@ -213,7 +225,7 @@ def mrf_half_step_kernel(
             blk(lambda i: (i, 0), width),
             blk(lambda i: (jnp.minimum(i + 1, n_blocks - 1), 0), width),
             blk(lambda i: (i, 0), width),  # evidence
-            blk(lambda i: (i, 0), n_words_cols),  # random words
+            word_planes_spec(want_words[1] // width, block_h, width, vmem),
             pl.BlockSpec((1, exp_table.shape[1]), lambda i: (0, 0),
                          memory_space=vmem),
         ],
@@ -223,7 +235,7 @@ def mrf_half_step_kernel(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(labels, labels, labels, evidence, words, exp_table)
+    )(labels, labels, labels, evidence, word_planes(words, width), exp_table)
 
 
 def mrf_round_step(
@@ -320,7 +332,7 @@ def mrf_halo_half_step_kernel(
 
     kernel = functools.partial(
         _mrf_halo_kernel, parity=parity, theta=theta, h=h, n_labels=n_labels,
-        data_cost=data_cost, x0=spec.x0, dx=spec.dx, lut_size=spec.size,
+        data_cost=data_cost, x0=spec.x0, inv_dx=spec.inv_dx, lut_size=spec.size,
         precision=precision, total_steps=total_steps, block_h=block_h,
         n_blocks=n_blocks, width=width,
     )
@@ -334,7 +346,6 @@ def mrf_halo_half_step_kernel(
         return pl.BlockSpec((rows, cols), lambda i: (0, 0),
                             memory_space=vmem)
 
-    n_words_cols = words.shape[1]
     return pl.pallas_call(
         kernel,
         grid=(n_blocks,),
@@ -346,7 +357,7 @@ def mrf_halo_half_step_kernel(
             blk(lambda i: (i, 0), width),
             blk(lambda i: (jnp.minimum(i + 1, n_blocks - 1), 0), width),
             blk(lambda i: (i, 0), width),  # evidence
-            blk(lambda i: (i, 0), n_words_cols),  # random words
+            word_planes_spec(want_words[1] // width, block_h, width, vmem),
             pl.BlockSpec((1, exp_table.shape[1]), lambda i: (0, 0),
                          memory_space=vmem),
         ],
@@ -356,8 +367,8 @@ def mrf_halo_half_step_kernel(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(row0, up_halo, down_halo, labels, labels, labels, evidence, words,
-      exp_table)
+    )(row0, up_halo, down_halo, labels, labels, labels, evidence,
+      word_planes(words, width), exp_table)
 
 
 def mrf_sharded_round_step(

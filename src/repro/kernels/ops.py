@@ -1,9 +1,9 @@
 """Public jit'd entry points for the Pallas kernels.
 
 These wrappers own all layout plumbing (lane padding, batch padding, random
-word generation, interpret-mode auto-detection) so callers see clean shapes.
-On non-TPU backends the kernels run in interpret mode (Python evaluation of
-the kernel body) — the TPU lowering path is identical.
+word generation, interpret-mode selection) so callers see clean shapes.
+On CPU the kernels run in interpret mode (the kernel body evaluated as XLA
+ops); on TPU Mosaic compiles them; `compat.pallas_interpret` decides.
 """
 
 from __future__ import annotations
@@ -13,6 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core import compat
 from repro.core import ky as ky_core
 from repro.core.interp import LUTSpec
 from repro.kernels import interp_lut as _interp_lut
@@ -23,7 +24,7 @@ LANES = 128
 
 def _auto_interpret(interpret: bool | None) -> bool:
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        return compat.pallas_interpret()
     return interpret
 
 

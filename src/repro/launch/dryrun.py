@@ -124,6 +124,7 @@ def run_cell(arch: str, cell: str, mesh_kind: str, out_dir: str,
         hbm_bytes=hc.hbm_bytes,
         collective_bytes=hc.collective_bytes,
         n_chips=1,  # the walked program is the per-device SPMD program
+        device_kind=rl.TARGET_KIND,  # a CPU-side sweep: priced for v5e
         model_flops=rl.model_flops(cfg, spec["kind"], seq, batch) / n_chips,
     )
     coll = rl.CollectiveStats(

@@ -11,8 +11,9 @@ collective_bytes is parsed out of the post-SPMD optimized HLO
 (``compiled.as_text()``) by summing the result sizes of every all-gather /
 all-reduce / reduce-scatter / all-to-all / collective-permute.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI (harness-specified).
+Hardware constants come from `PEAKS`, one row per `device_kind` with its
+published source; a kind that is not in the table raises.  CPU-side static
+sweeps name the chip they target (`TARGET_KIND`) explicitly.
 
 Known-limits note (documented, accounted for in the tables): XLA's HLO cost
 analysis reports a while-loop body ONCE, not multiplied by its trip count.
@@ -26,9 +27,41 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s / chip
-ICI_BW = 50e9  # bytes/s / link (per chip, one direction)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # ICI bytes/s per link, one direction
+    source: str
+
+
+# Published per-chip peaks keyed by `jax.Device.device_kind`.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12,
+        hbm_bw=819e9,
+        # 1,600 Gbit/s chip-to-chip interconnect over the chip's four links
+        ici_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e" (system architecture)',
+    ),
+}
+
+# The chip the kernels are written for: what CPU-side static sweeps and
+# rehearsals (no chip attached) price their programs against.
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of one chip kind; an unknown kind raises rather
+    than borrowing another chip's numbers."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add a "
+            "sourced row to launch/roofline.PEAKS"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -134,19 +167,24 @@ class Roofline:
     hbm_bytes: float
     collective_bytes: float
     n_chips: int
+    device_kind: str
     model_flops: float = 0.0
 
     @property
+    def peaks(self) -> ChipPeaks:
+        return peaks(self.device_kind)
+
+    @property
     def t_compute(self) -> float:
-        return self.flops / (self.n_chips * PEAK_FLOPS)
+        return self.flops / (self.n_chips * self.peaks.flops)
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / (self.n_chips * HBM_BW)
+        return self.hbm_bytes / (self.n_chips * self.peaks.hbm_bw)
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / (self.n_chips * ICI_BW)
+        return self.collective_bytes / (self.n_chips * self.peaks.ici_bw)
 
     @property
     def bottleneck(self) -> str:
@@ -167,6 +205,7 @@ class Roofline:
             "hbm_bytes": self.hbm_bytes,
             "collective_bytes": self.collective_bytes,
             "n_chips": self.n_chips,
+            "device_kind": self.device_kind,
             "model_flops": self.model_flops,
             "t_compute_s": self.t_compute,
             "t_memory_s": self.t_memory,

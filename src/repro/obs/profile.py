@@ -106,6 +106,9 @@ class BucketProfile:
     t_collective_s: float
     bottleneck: str
     capture_s: float  # wall time of the AOT compile+analysis (diagnostic)
+    # Pallas kernels Mosaic compiled into the executable (0 when the
+    # kernels ran interpreted, or the bucket is unfused)
+    mosaic_kernels: int = 0
 
     @property
     def roofline_s(self) -> float:
@@ -126,16 +129,34 @@ class BucketProfile:
             "t_collective_s": self.t_collective_s,
             "roofline_s": self.roofline_s,
             "bottleneck": self.bottleneck,
+            "mosaic_kernels": self.mosaic_kernels,
         }
         if not deterministic:
             d["capture_s"] = round(self.capture_s, 6)
         return d
 
 
+# how a Mosaic-compiled Pallas kernel appears in an executable's HLO
+MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+
+
+def target_kind() -> str:
+    """The chip kind the roofline prices executables against: the chip this
+    process runs on, or — on CPU, where nothing runs on a chip — the
+    `roofline.TARGET_KIND` the kernels are written for."""
+    import jax
+
+    dev = jax.devices()[0]
+    return dev.device_kind if dev.platform == "tpu" else \
+        roofline_mod.TARGET_KIND
+
+
 class ProfileRegistry:
     """Cache of :class:`BucketProfile` keyed by executable signature."""
 
-    def __init__(self):
+    def __init__(self, device_kind: str | None = None):
+        self.device_kind = device_kind or target_kind()
+        roofline_mod.peaks(self.device_kind)  # an unknown kind fails here
         self.profiles: dict = {}
         self.hits = 0
         self.errors: dict = {}
@@ -155,7 +176,8 @@ class ProfileRegistry:
             return prof
         t0 = time.perf_counter()
         compiled = lower().compile()
-        cost = hlo_cost_mod.analyze(compiled.as_text())
+        text = compiled.as_text()
+        cost = hlo_cost_mod.analyze(text)
         xla_flops = xla_bytes = 0.0
         try:
             ca = compiled.cost_analysis()
@@ -168,6 +190,7 @@ class ProfileRegistry:
         roof = roofline_mod.Roofline(
             flops=cost.flops, hbm_bytes=cost.hbm_bytes,
             collective_bytes=cost.collective_bytes, n_chips=n_chips,
+            device_kind=self.device_kind,
         )
         prof = BucketProfile(
             sig=sig, meta=dict(meta),
@@ -180,6 +203,7 @@ class ProfileRegistry:
             t_compute_s=roof.t_compute, t_memory_s=roof.t_memory,
             t_collective_s=roof.t_collective, bottleneck=roof.bottleneck,
             capture_s=time.perf_counter() - t0,
+            mosaic_kernels=text.count(MOSAIC_CALL),
         )
         self.profiles[sig] = prof
         if tracer.enabled():
@@ -260,6 +284,7 @@ def capture_program(program, *, n_chains, n_iters, burn_in=50, thin=1,
     import jax.numpy as jnp
 
     from repro.compile import backend as backend_mod
+    from repro.core import compat
 
     reg = registry if registry is not None else get()
     if reg is None:
@@ -272,7 +297,7 @@ def capture_program(program, *, n_chains, n_iters, burn_in=50, thin=1,
         reg.hits += 1
         return reg.profiles[sig]
     ex = program.schedule_executable()
-    interpret = jax.default_backend() != "tpu"
+    interpret = compat.pallas_interpret()
     if program.kind == "bn":
         def lower():
             return backend_mod._run_bn_rounds.lower(
@@ -299,7 +324,7 @@ def capture_program(program, *, n_chains, n_iters, burn_in=50, thin=1,
 
 # -- joining static costs against measured dispatch walls -------------------
 
-def join_dispatches(profiles, events) -> dict:
+def join_dispatches(profiles, events, device_kind: str | None = None) -> dict:
     """Join cached static costs against measured ``dispatch`` spans.
 
     ``profiles`` maps sig -> :class:`BucketProfile` (or its dict form);
@@ -311,7 +336,10 @@ def join_dispatches(profiles, events) -> dict:
     route-qualified ``profile_sig`` and the sharded engines capture the
     shard_map executable under the same signature, so a sharded dispatch
     without a profile is an unattributed finding, not a skip.
+    Comm rows compare against `device_kind`'s ICI peak (`target_kind()`
+    when None).
     """
+    ici_bw = roofline_mod.peaks(device_kind or target_kind()).ici_bw
     rows: dict = {}
     unattributed: dict = {}
     n_dispatches = 0
@@ -383,7 +411,7 @@ def join_dispatches(profiles, events) -> dict:
             if c["measured_total_s"] > 0 else None
         )
         c["peak_frac"] = (
-            min(1.0, c["achieved_bw"] / roofline_mod.ICI_BW)
+            min(1.0, c["achieved_bw"] / ici_bw)
             if c["achieved_bw"] else None
         )
         comm_rows.append(c)
@@ -398,12 +426,12 @@ def join_dispatches(profiles, events) -> dict:
 
 def write_profile(path, registry, events) -> dict:
     """Join + write the ``profile.json`` artifact; returns the record."""
-    joined = join_dispatches(registry.profiles, events)
+    joined = join_dispatches(registry.profiles, events, registry.device_kind)
+    pk = roofline_mod.peaks(registry.device_kind)
     rec = {
         "schema": 1,
-        "peaks": {"flops": roofline_mod.PEAK_FLOPS,
-                  "hbm_bw": roofline_mod.HBM_BW,
-                  "ici_bw": roofline_mod.ICI_BW},
+        "peaks": {"device_kind": registry.device_kind, "flops": pk.flops,
+                  "hbm_bw": pk.hbm_bw, "ici_bw": pk.ici_bw},
         "buckets": registry.rows(deterministic=False),
         "capture_hits": registry.hits,
         "capture_errors": dict(sorted(registry.errors.items())),
@@ -456,7 +484,8 @@ def static_profile_sweep(quick: bool = False) -> list:
     from repro.compile import compile_graph
     from repro.core.graphs import GridMRF, bn_repository_replica
 
-    reg = ProfileRegistry()
+    # a static sweep: priced for the chip the kernels target, whatever runs it
+    reg = ProfileRegistry(device_kind=roofline_mod.TARGET_KIND)
     models = SWEEP_BN_MODELS[:1] if quick else SWEEP_BN_MODELS
     progs = [compile_graph(bn_repository_replica(name)) for name in models]
     progs.append(compile_graph(GridMRF(

@@ -210,4 +210,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.core import compat
+
+    compat.setup_compile_cache()  # the CLI only: main() runs in tests too
     raise SystemExit(main())

@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.analysis import kernel_lint
 from repro.compile import backend as backend_mod
+from repro.core import compat
 from repro.core import mrf as mrf_mod
 from repro.obs import profile as profile_mod
 from repro.obs import tracer
@@ -435,7 +436,7 @@ def _execute_bucket(
         kw = dict(
             n_chains=key.n_chains, n_iters=key.n_iters, burn_in=key.burn_in,
             thin=key.thin, sampler=key.sampler, return_state=run_state,
-            fused=key.fused, interpret=jax.default_backend() != "tpu",
+            fused=key.fused, interpret=compat.pallas_interpret(),
         )
         if profile_mod.enabled():
             profile_mod.capture_bucket(
@@ -482,7 +483,7 @@ def _execute_bucket(
     a = (mrf, parities, imgs, seeds_q, pmask_q, pvals_q, carry_q, totals_q)
     kw = dict(
         n_chains=key.n_chains, n_iters=key.n_iters, sampler=key.sampler,
-        fused=key.fused, interpret=jax.default_backend() != "tpu",
+        fused=key.fused, interpret=compat.pallas_interpret(),
         eager=eager, return_state=run_state,
     )
     if profile_mod.enabled():

@@ -207,9 +207,20 @@ class Executor:
     ) -> list[QueryResult]:
         """Real execution only (no pool booking): the path `dispatch` runs
         and `Engine.calibrate`'s timed warmup re-runs, so warmup measures
-        exactly what serving will pay — sharded route included."""
-        if route == "sharded" and self._shard_mesh() is not None:
-            return self._run_sharded(program, key, qs, return_state)
+        exactly what serving will pay — sharded route included.
+
+        A sharded route without a mesh of its width raises on TPU: running
+        it on one chip while booking it as sharded would report a slice
+        that never existed.  On CPU it runs through the vmap path — the
+        one-device host models the slice's timing only."""
+        if route == "sharded":
+            if self._shard_mesh() is not None:
+                return self._run_sharded(program, key, qs, return_state)
+            if jax.default_backend() != "cpu":
+                raise RuntimeError(
+                    f"bucket routed sharded over {self.config.shard_width} "
+                    f"devices, but this process has {len(jax.devices())}"
+                )
         return batcher_mod.execute_bucket(
             program, key, qs, self.pad_sizes, return_state=return_state
         )

@@ -216,3 +216,43 @@ def test_cross_check_catches_divergent_lowering():
     ex.round_groups = list(reversed(ex.round_groups))
     with pytest.raises(BackendMismatch):
         cross_check(prog, ex)
+
+
+# ---------------------------------------------------------------------------
+# compat: the interpret switch and the compile-cache placement
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("backend,interpret", [
+    ("tpu", False), ("cpu", True), ("gpu", None),
+])
+def test_pallas_interpret_follows_the_backend(monkeypatch, backend,
+                                              interpret):
+    """Kernels compile on TPU, interpret on CPU, and refuse anything else
+    rather than silently interpreting on an accelerator."""
+    from repro.core import compat
+
+    monkeypatch.setattr(compat.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="neither"):
+            compat.pallas_interpret()
+    else:
+        assert compat.pallas_interpret() is interpret
+
+
+def test_compile_cache_dir_from_env_else_checkout(monkeypatch, tmp_path):
+    from repro.core import compat
+
+    set_dirs = []
+    monkeypatch.setattr(
+        compat.jax.config, "update",
+        lambda name, value: set_dirs.append((name, value)),
+    )
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compat.setup_compile_cache() == str(tmp_path)
+    assert set_dirs == []  # JAX reads the variable itself
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = str(compat.CHECKOUT / ".jax_cache")
+    assert compat.setup_compile_cache() == want
+    assert set_dirs == [("jax_compilation_cache_dir", want)]
+    assert (compat.CHECKOUT / "src" / "repro").is_dir()

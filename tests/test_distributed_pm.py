@@ -16,8 +16,9 @@ _SCRIPT = textwrap.dedent(
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np, jax, jax.numpy as jnp
+    from repro.core import compat
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = compat.make_mesh((2, 4), ("data", "model"))
 
     from repro.core.graphs import GridMRF, random_bayesnet
     from repro.core import mrf as mrf_mod

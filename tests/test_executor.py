@@ -257,6 +257,27 @@ def test_executor_sharded_dispatch_occupies_the_slice():
     assert rec2.route == "vmap" and rec2.n_workers == 1
 
 
+def test_sharded_route_without_its_mesh_raises_on_tpu(monkeypatch):
+    """Off CPU, a bucket routed sharded over more devices than the process
+    has must fail loudly — never run on one chip while booked as a slice
+    (the CPU branch above models the slice's timing only)."""
+    from repro.runtime import executor as executor_mod
+
+    ex = Executor(
+        ExecutorConfig(n_workers=4, shard_width=4, shard_min_sites=64),
+        Calibrator(), (4,),
+    )
+    graph = compile_ir.from_mrf(GridMRF(8, 8, 2))
+    prog = compile_graph(graph)
+    qs = [Query(qid=0, model="g", image=np.zeros((8, 8), np.int32),
+                n_chains=2, n_iters=2)]
+    key = bucket_key(qs[0], graph, "schedule")
+    assert ex.route(prog, key) == "sharded" and ex._shard_mesh() is None
+    monkeypatch.setattr(executor_mod.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="routed sharded over 4"):
+        ex.execute(prog, key, qs, "sharded")
+
+
 # ---------------------------------------------------------------------------
 # Calibrator
 # ---------------------------------------------------------------------------

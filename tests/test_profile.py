@@ -363,3 +363,24 @@ def test_check_static_cost_reports_missing_and_new_sigs():
     assert rep.meta["cost_compared"] == 1
     assert rep.meta["cost_missing"] == [_cost_rows()[0]["sig"]]
     assert rep.meta["cost_new"] == ["run|cccc|new"]
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """One sourced peak row per chip kind; an unknown kind raises instead
+    of borrowing another chip's numbers."""
+    from repro.launch import roofline
+
+    v5e = roofline.peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9) and v5e.source
+    assert roofline.TARGET_KIND in roofline.PEAKS
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks("TPU v4")
+    roof = roofline.Roofline(flops=197e12, hbm_bytes=0.0,
+                             collective_bytes=0.0, n_chips=1,
+                             device_kind="TPU v5 lite")
+    assert roof.t_compute == 1.0 and roof.bottleneck == "compute"
+    with pytest.raises(ValueError):
+        roofline.Roofline(flops=1.0, hbm_bytes=0.0, collective_bytes=0.0,
+                          n_chips=1, device_kind="cpu").t_compute
+    with pytest.raises(ValueError):
+        profile_mod.ProfileRegistry(device_kind="cpu")
