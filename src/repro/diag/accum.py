@@ -176,7 +176,7 @@ class QualitySnapshot:
     finite: bool
 
     def brief(self) -> dict:
-        """The scalar row serving metrics / trace instants carry around."""
+        """The scalar row serving metrics carry around."""
         return {
             "rhat_max": self.rhat_max,
             "ess_min": self.ess_min,

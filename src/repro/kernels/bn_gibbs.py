@@ -58,6 +58,10 @@ pl = compat.pallas()
 # must be rejected loudly by the callers (never silently fall back).
 FUSED_BN_SAMPLERS = ("lut_ky", "exact_ky")
 
+# The kernel's custom-call name in compiled HLO and in device traces,
+# stable across edits to the surrounding program.
+KERNEL_NAME = "bn_gibbs_kernel"
+
 
 def check_fused_sampler(sampler: str) -> None:
     """The fused-BN sampler gate, shared by every entry layer (program.run,
@@ -375,6 +379,7 @@ def _fused_rounds_call(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(vals_p, *tables, words, arena, tab)
     return out[:b, :n]
 

@@ -1,12 +1,23 @@
-"""Structured tracing: nestable spans + typed counters in a ring buffer.
+"""Structured tracing: nestable spans + typed counters in a ring buffer,
+and wall spans on the `jax.profiler` timeline.
 
 The whole stack (compile passes, the runtime event loop, the executor's
 dispatches, kernel entry points) calls into this module unconditionally;
-when tracing is *off* — the default — every entry point is a single
-module-attribute check that returns a shared no-op object, so the serving
-hot path pays no allocation and no branch beyond `if _TRACER is None`.
-Enable via the `REPRO_TRACE=1` environment variable (checked once at
-import) or `repro.obs.enable()`.
+when tracing is *off* — the default — every entry point is one check that
+returns a shared no-op object, so the serving hot path pays no allocation.
+Enable the ring buffer via the `REPRO_TRACE=1` environment variable
+(checked once at import) or `repro.obs.enable()`.
+
+Wall spans (`span()`) have a second sink: whenever a `jax.profiler` trace
+is recording, each one also opens a `jax.profiler.TraceAnnotation` of the
+same name, its arguments attached as event stats.  The annotation lands on
+the profiler's host plane, whose clock the device planes share, so the
+host phases of the serving path line up with the device's ops and idle
+gaps — with no `enable()` and no ring-buffer events in the profiled run.
+A live span opened inside another live span inherits its `dispatch`
+number, so every span of one bucket dispatch carries the same identifier.
+The span check is `_TRACER is None` and then the profiler's own
+`TraceMe.is_enabled()`: a few tens of nanoseconds with both off.
 
 Two clocks, deliberately:
 
@@ -35,9 +46,15 @@ from __future__ import annotations
 import collections
 import dataclasses
 import os
+import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 DEFAULT_CAPACITY = 1 << 16
+
+# whether a jax.profiler session records: the profiler's own TraceMe check
+profiling = TraceAnnotation.is_enabled
 
 
 @dataclasses.dataclass
@@ -128,12 +145,14 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """A live wall-clocked span (context manager).  `set()` attaches
-    deterministic attributes, `set_wall()` wall-derived ones."""
+    """A live wall-clocked span (context manager) on the ring buffer, the
+    profiler's timeline, or both.  `set()` attaches deterministic
+    attributes, `set_wall()` wall-derived ones (ring buffer only)."""
 
-    __slots__ = ("_tracer", "name", "cat", "track", "args", "wargs", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "track", "args", "wargs", "_t0",
+                 "_annotation")
 
-    def __init__(self, tracer: Tracer, name: str, cat: str,
+    def __init__(self, tracer: Tracer | None, name: str, cat: str,
                  track: str | None, args: dict):
         self._tracer = tracer
         self.name = name
@@ -142,25 +161,54 @@ class _Span:
         self.args = args
         self.wargs: dict = {}
         self._t0 = 0.0
+        self._annotation = None
 
     def __enter__(self):
+        live = _live_spans()
+        if live and "dispatch" not in self.args:
+            number = live[-1].args.get("dispatch")
+            if number is not None:
+                self.args["dispatch"] = number
+        live.append(self)
+        if profiling():
+            self._annotation = TraceAnnotation(self.name, **self.args)
+            self._annotation.__enter__()
         # the wall half of the span's dual timestamps (see module docstring)
         self._t0 = time.perf_counter()  # lint: allow[wallclock-in-sim]
         return self
 
     def set(self, **args) -> None:
         self.args.update(args)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**args)
 
     def set_wall(self, **wargs) -> None:
         self.wargs.update(wargs)
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()  # lint: allow[wallclock-in-sim]
-        self._tracer.emit(
-            "span", self.name, self.cat, self.track,
-            wall_t0=self._t0, wall_t1=t1, args=self.args, wargs=self.wargs,
-        )
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
+        _live_spans().remove(self)
+        if self._tracer is not None:
+            self._tracer.emit(
+                "span", self.name, self.cat, self.track,
+                wall_t0=self._t0, wall_t1=t1, args=self.args,
+                wargs=self.wargs,
+            )
         return False
+
+
+_LOCAL = threading.local()
+
+
+def _live_spans() -> list:
+    """This thread's stack of open live spans (innermost last)."""
+    stack = getattr(_LOCAL, "spans", None)
+    if stack is None:
+        stack = _LOCAL.spans = []
+    return stack
 
 
 _TRACER: Tracer | None = None
@@ -188,10 +236,11 @@ def disable() -> None:
 
 
 def span(name: str, cat: str = "host", track: str | None = None, **args):
-    """Context manager timing a wall-clocked span.  Off: returns the
-    shared no-op span."""
+    """Context manager timing a wall-clocked span into the ring buffer
+    and, while a `jax.profiler` trace records, onto its host timeline.
+    With neither on: returns the shared no-op span."""
     t = _TRACER
-    if t is None:
+    if t is None and not profiling():
         return NULL_SPAN
     return _Span(t, name, cat, track, args)
 

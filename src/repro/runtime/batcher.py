@@ -375,20 +375,19 @@ def execute_bucket(
     A `diagnostics` bucket additionally threads the streaming quality
     accumulator through every lane and summarizes it into
     `QueryResult.quality` (the chain state is requested internally either
-    way, but only attached to `carry` when the caller asked)."""
+    way, but only attached to `carry` when the caller asked).
+
+    Four wall spans split the call (`repro.obs.tracer`): `batch/prepare`
+    (padding, evidence and seed arrays, carry stacking, the executable
+    lookup and the fused first-use guard), `batch/launch` (the bucket
+    executable's call, until it returns), `batch/fetch` (the host blocked
+    on the device, and the copy back) and `batch/unpack` (the per-query
+    results: lane states and quality)."""
     n_real = len(queries)
-    n_pad = pad_size(n_real, pad_sizes)
-    with tracer.span(
-        "execute_bucket", cat="batch",
-        kind=key.kind, sampler=key.sampler, fused=key.fused,
-        diagnostics=key.diagnostics,
-        resumed=key.resumed, n_real=n_real, n_padded=n_pad,
-        pad_efficiency=round(n_real / n_pad, 6) if n_pad else 0.0,
-        n_iters=key.n_iters, n_chains=key.n_chains,
-    ):
-        return _execute_bucket(
-            program, key, queries, n_real, n_pad, return_state
-        )
+    return _execute_bucket(
+        program, key, queries, n_real, pad_size(n_real, pad_sizes),
+        return_state,
+    )
 
 
 def _lane_quality(states, i: int, cards=None, free_mask=None) -> dict:
@@ -405,48 +404,68 @@ def _execute_bucket(
     program, key: BucketKey, queries: list[Query],
     n_real: int, n_pad: int, return_state: bool,
 ) -> list[QueryResult]:
-    padded = list(queries) + [queries[0]] * (n_pad - n_real)
-    seeds_q = _seed_array(padded)
-    carry_q = _stack_carries(padded) if key.resumed else None
     # diagnostics needs the post-run chain state (the accumulator lives
     # there) even when the caller doesn't want the carry back
     run_state = return_state or key.diagnostics
-    totals_q = None
-    if key.diagnostics and not key.resumed:
-        # each lane's accumulator splits at its query's *total* budget —
-        # a fresh query's n_iters is that total (the engine rewrites
-        # n_iters only on continuation re-enqueues)
-        totals_q = jnp.asarray([q.n_iters for q in padded], jnp.int32)
-    if key.kind == "bn":
-        n = program.ir.n_nodes
-        ev_mask = np.zeros(n, bool)
-        ev_mask[list(key.clamp_nodes)] = True
-        ev_vals = np.zeros((n_pad, n), np.int64)
-        for i, q in enumerate(padded):
-            for node, val in (q.evidence or {}).items():
-                ev_vals[i, int(node)] = int(val)
-        groups = program.clamped_executable(key.clamp_nodes, key.backend)
-        if key.fused:
-            # same first-use guarantee the single-program path gets
-            program.ensure_fused_cross_check(key.sampler)
-        a = (
-            program.cbn, groups, jnp.asarray(ev_vals, jnp.int32),
-            jnp.asarray(ev_mask), seeds_q, carry_q, totals_q,
-        )
-        kw = dict(
-            n_chains=key.n_chains, n_iters=key.n_iters, burn_in=key.burn_in,
-            thin=key.thin, sampler=key.sampler, return_state=run_state,
-            fused=key.fused, interpret=compat.pallas_interpret(),
-        )
+    with tracer.span("batch/prepare", cat="batch"):
+        padded = list(queries) + [queries[0]] * (n_pad - n_real)
+        prepare = _prepare_bn if key.kind == "bn" else _prepare_mrf
+        bucket, a, kw, unpack = prepare(program, key, padded, run_state)
         if profile_mod.enabled():
             profile_mod.capture_bucket(
-                program, key, n_pad, _bn_bucket, a, kw,
-                model=queries[0].model,
+                program, key, n_pad, bucket, a, kw, model=queries[0].model,
             )
-        out = _bn_bucket(*a, **kw)
-        marg, vals = out[0], out[1]
-        states = out[2] if run_state else None
-        marg, vals = np.asarray(marg), np.asarray(vals)
+    with tracer.span("batch/launch", cat="batch"):
+        out = bucket(*a, **kw)
+    with tracer.span("batch/fetch", cat="batch"):
+        if key.kind == "bn":
+            host = (np.asarray(out[0]), np.asarray(out[1]))
+            states = out[2] if run_state else None
+        else:
+            labels, states = out if run_state else (out, None)
+            host = np.asarray(labels)
+    with tracer.span("batch/unpack", cat="batch"):
+        return unpack(host, states, queries, n_real, return_state)
+
+
+def _totals(key: BucketKey, padded: list[Query]):
+    """Each lane's total sweep budget, for a fresh diagnostics bucket: the
+    accumulator splits at its query's *total* budget — a fresh query's
+    n_iters is that total (the engine rewrites n_iters only on continuation
+    re-enqueues)."""
+    if key.diagnostics and not key.resumed:
+        return jnp.asarray([q.n_iters for q in padded], jnp.int32)
+    return None
+
+
+def _prepare_bn(program, key: BucketKey, padded: list[Query],
+                run_state: bool):
+    """(executable, args, static kwargs, unpack) of a BN bucket."""
+    seeds_q = _seed_array(padded)
+    carry_q = _stack_carries(padded) if key.resumed else None
+    n = program.ir.n_nodes
+    ev_mask = np.zeros(n, bool)
+    ev_mask[list(key.clamp_nodes)] = True
+    ev_vals = np.zeros((len(padded), n), np.int64)
+    for i, q in enumerate(padded):
+        for node, val in (q.evidence or {}).items():
+            ev_vals[i, int(node)] = int(val)
+    groups = program.clamped_executable(key.clamp_nodes, key.backend)
+    if key.fused:
+        # same first-use guarantee the single-program path gets
+        program.ensure_fused_cross_check(key.sampler)
+    a = (
+        program.cbn, groups, jnp.asarray(ev_vals, jnp.int32),
+        jnp.asarray(ev_mask), seeds_q, carry_q, _totals(key, padded),
+    )
+    kw = dict(
+        n_chains=key.n_chains, n_iters=key.n_iters, burn_in=key.burn_in,
+        thin=key.thin, sampler=key.sampler, return_state=run_state,
+        fused=key.fused, interpret=compat.pallas_interpret(),
+    )
+
+    def unpack(host, states, queries, n_real, return_state):
+        marg, vals = host
         cards = np.asarray(program.cbn.cards)
         return [
             QueryResult(
@@ -460,6 +479,15 @@ def _execute_bucket(
             )
             for i, q in enumerate(queries)
         ]
+
+    return _bn_bucket, a, kw, unpack
+
+
+def _prepare_mrf(program, key: BucketKey, padded: list[Query],
+                 run_state: bool):
+    """(executable, args, static kwargs, unpack) of an MRF bucket."""
+    seeds_q = _seed_array(padded)
+    carry_q = _stack_carries(padded) if key.resumed else None
     mrf = program.mrf
     imgs = jnp.asarray(
         np.stack([np.asarray(q.image, np.int32) for q in padded])
@@ -480,32 +508,30 @@ def _execute_bucket(
         parities, eager = ex.parities, False
     else:
         parities, eager = (0, 1), True
-    a = (mrf, parities, imgs, seeds_q, pmask_q, pvals_q, carry_q, totals_q)
+    a = (mrf, parities, imgs, seeds_q, pmask_q, pvals_q, carry_q,
+         _totals(key, padded))
     kw = dict(
         n_chains=key.n_chains, n_iters=key.n_iters, sampler=key.sampler,
         fused=key.fused, interpret=compat.pallas_interpret(),
         eager=eager, return_state=run_state,
     )
-    if profile_mod.enabled():
-        profile_mod.capture_bucket(
-            program, key, n_pad, _mrf_bucket, a, kw, model=queries[0].model,
-        )
-    out = _mrf_bucket(*a, **kw)
-    labels, states = (out if run_state else (out, None))
-    labels = np.asarray(labels)
 
     def mrf_free(i):
         if pmask_q is None:
             return None
         return ~np.asarray(pmask_q[i]).reshape(-1)
 
-    return [
-        QueryResult(
-            qid=q.qid, model=q.model, kind="mrf", marginals=None,
-            final_state=labels[i], arrival_s=q.arrival_s, batch_size=n_real,
-            carry=_lane_state(states, i) if return_state else None,
-            quality=_lane_quality(states, i, free_mask=mrf_free(i))
-            if key.diagnostics else None,
-        )
-        for i, q in enumerate(queries)
-    ]
+    def unpack(labels, states, queries, n_real, return_state):
+        return [
+            QueryResult(
+                qid=q.qid, model=q.model, kind="mrf", marginals=None,
+                final_state=labels[i], arrival_s=q.arrival_s,
+                batch_size=n_real,
+                carry=_lane_state(states, i) if return_state else None,
+                quality=_lane_quality(states, i, free_mask=mrf_free(i))
+                if key.diagnostics else None,
+            )
+            for i, q in enumerate(queries)
+        ]
+
+    return _mrf_bucket, a, kw, unpack

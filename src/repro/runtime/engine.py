@@ -64,11 +64,10 @@ class EngineConfig:
     fused: bool = False
     # thread the streaming quality accumulator (repro.diag) through every
     # bucket: each served query's QueryResult.quality carries its R-hat/ESS
-    # brief, the metrics grow rhat_max/ess_min columns, and the tracer
-    # emits per-query `quality` instants.  Draw streams are bit-identical
-    # either way, on every route — fused sharded dispatches thread the
-    # accumulator through the shard_map body (its site/chain moment leaves
-    # shard with the state)
+    # brief and the metrics grow rhat_max/ess_min columns.  Draw streams
+    # are bit-identical either way, on every route — fused sharded
+    # dispatches thread the accumulator through the shard_map body (its
+    # site/chain moment leaves shard with the state)
     diagnostics: bool = False
     pipeline: str = "runtime"  # pass list incl. merge_small_colors
     mesh_shape: tuple[int, int] = (4, 4)
@@ -141,6 +140,9 @@ class Engine:
         self.metrics = RuntimeMetrics()
         self._queue: list[Query] = []
         self.shed_qids: list[int] = []
+        # bucket dispatches over the engine's life: the `dispatch` number
+        # every wall span of one dispatch carries
+        self._dispatches = 0
 
     # -- admission ---------------------------------------------------------
 
@@ -268,7 +270,14 @@ class Engine:
         at the simulated clock, bucket flush on fill-or-window, dispatch
         onto the worker pool at the calibrated service time.  Long queries
         re-enter the arrival queue between slices as continuations carrying
-        their chain state — bit-exact with an unsliced run."""
+        their chain state — bit-exact with an unsliced run.
+
+        Wall spans (`repro.obs.tracer`) mark the host's phases: one
+        `engine/admit` per admission pass, and per bucket dispatch an
+        `engine/dispatch` holding the batcher's `batch/*` spans, then
+        `engine/book` (service prediction, pool booking, the
+        `BatchRecord`, the series) and `engine/requeue` (continuations
+        and results).  Each carries the dispatch's number, `dispatch`."""
         cfg = self.config
         # wall-metric half of the dual clock, not the sim's event time
         wall0 = time.perf_counter()  # lint: allow[wallclock-in-sim]
@@ -316,6 +325,11 @@ class Engine:
         return_state = cfg.slice_iters is not None
 
         def admit():
+            with tracer.span("engine/admit", cat="engine",
+                             dispatch=self._dispatches):
+                admit_due()
+
+        def admit_due():
             nonlocal seq
             while heap and heap[0][0] <= clock:
                 _, _, _, q = heapq.heappop(heap)
@@ -404,73 +418,96 @@ class Engine:
                 clock = min(h for h in horizons if h > clock)
                 admit()
                 continue
-            key = min(ready, key=lambda k: (oldest(k), repr(k)))
-            qs = sorted(
-                pending[key], key=lambda q: (q.arrival_s, q.qid)
-            )[: cfg.max_batch]
-            taken = {q.qid for q in qs}
-            remaining = [q for q in pending[key] if q.qid not in taken]
-            # the flush made room: parked continuations re-enter first (in
-            # park order), up to the bound
-            parked = overflow.get(key, [])
-            while parked and not admission.queue_full(len(remaining)):
-                remaining.append(parked.pop(0))
-                admission.note_depth(len(remaining))
-            if not parked:
-                overflow.pop(key, None)
-            if remaining:
-                pending[key] = remaining
-            else:
-                del pending[key]
-            tracer.instant(
-                "flush", cat="runtime", sim_t=clock,
-                model=qs[0].model, kind=key.kind, n_queries=len(qs),
-                full=len(qs) >= cfg.max_batch,
-            )
-            batch, rec = executor.dispatch(
-                programs[key], key, qs, clock, return_state=return_state
-            )
-            self.metrics.record_batch(rec)
-            series.histogram(
-                "pad_efficiency", boundaries=timeseries.PAD_EFF_BOUNDARIES,
-            ).observe(rec.start_s, rec.n_real / max(rec.n_padded, 1))
-            series.histogram("bucket_service_s").observe(
-                rec.start_s, rec.service_s
-            )
-            # cumulative flush-window stall across the pool, sampled per
-            # dispatch: the window/ladder autotuner's minimization target
-            series.gauge("worker_stall_s").sample(
-                rec.finish_s, round(sum(executor.pool.stall_s), 9)
-            )
-            done = []
-            for q, r in zip(qs, batch):
-                left = q.n_iters - key.n_iters
-                if left > 0:
-                    # continuation: same query, chain state attached, the
-                    # remaining budget, re-arriving when its slice finished
-                    # (a copy — submitted Query objects stay pristine)
-                    cont = dataclasses.replace(
-                        q, carry=r.carry, n_iters=left,
-                        arrival_s=rec.finish_s,
-                    )
-                    heapq.heappush(heap, (rec.finish_s, cont.qid, seq, cont))
-                    seq += 1
+            number = self._dispatches
+            self._dispatches += 1
+            with tracer.span("engine/dispatch", cat="engine",
+                             dispatch=number) as span:
+                key = min(ready, key=lambda k: (oldest(k), repr(k)))
+                qs = sorted(
+                    pending[key], key=lambda q: (q.arrival_s, q.qid)
+                )[: cfg.max_batch]
+                taken = {q.qid for q in qs}
+                remaining = [q for q in pending[key] if q.qid not in taken]
+                # the flush made room: parked continuations re-enter first
+                # (in park order), up to the bound
+                parked = overflow.get(key, [])
+                while parked and not admission.queue_full(len(remaining)):
+                    remaining.append(parked.pop(0))
+                    admission.note_depth(len(remaining))
+                if not parked:
+                    overflow.pop(key, None)
+                if remaining:
+                    pending[key] = remaining
                 else:
-                    r.arrival_s = first_arrival[r.qid]
-                    r.carry = None  # slices are internal; results are final
-                    results[r.qid] = r
-                    done.append(r)
-                    series.histogram("query_latency_s").observe(
-                        rec.finish_s, r.latency_s
+                    del pending[key]
+                tracer.instant(
+                    "flush", cat="runtime", sim_t=clock,
+                    model=qs[0].model, kind=key.kind, n_queries=len(qs),
+                    full=len(qs) >= cfg.max_batch,
+                )
+                program = programs[key]
+                route = executor.batch_route(program, key, qs)
+                if span is not tracer.NULL_SPAN:
+                    n_padded = batcher_mod.pad_size(len(qs), cfg.pad_sizes)
+                    span.set(
+                        model=qs[0].model, kind=key.kind, route=route,
+                        sampler=key.sampler, fused=key.fused,
+                        diagnostics=key.diagnostics, resumed=key.resumed,
+                        n_real=len(qs), n_padded=n_padded,
+                        pad_efficiency=round(len(qs) / n_padded, 6),
+                        n_iters=key.n_iters, n_chains=key.n_chains,
                     )
-                    if r.quality is not None and tracer.enabled():
-                        # convergence lands on the timeline next to the
-                        # dispatch lanes that produced it
-                        tracer.instant(
-                            "quality", cat="quality", sim_t=rec.finish_s,
-                            qid=r.qid, model=r.model, **r.quality,
-                        )
-            self.metrics.record_queries(done)
+                batch, measured_s, lowered = executor.timed_execute(
+                    program, key, qs, route, return_state
+                )
+                with tracer.span("engine/book", cat="engine",
+                                 dispatch=number):
+                    rec = executor.book(
+                        program, key, qs, route, clock, batch, measured_s,
+                        lowered,
+                    )
+                    self.metrics.record_batch(rec)
+                    series.histogram(
+                        "pad_efficiency",
+                        boundaries=timeseries.PAD_EFF_BOUNDARIES,
+                    ).observe(rec.start_s, rec.n_real / max(rec.n_padded, 1))
+                    series.histogram("bucket_service_s").observe(
+                        rec.start_s, rec.service_s
+                    )
+                    # cumulative flush-window stall across the pool, sampled
+                    # per dispatch: the window/ladder autotuner's
+                    # minimization target
+                    series.gauge("worker_stall_s").sample(
+                        rec.finish_s, round(sum(executor.pool.stall_s), 9)
+                    )
+                with tracer.span("engine/requeue", cat="engine",
+                                 dispatch=number):
+                    done = []
+                    for q, r in zip(qs, batch):
+                        left = q.n_iters - key.n_iters
+                        if left > 0:
+                            # continuation: same query, chain state
+                            # attached, the remaining budget, re-arriving
+                            # when its slice finished (a copy — submitted
+                            # Query objects stay pristine)
+                            cont = dataclasses.replace(
+                                q, carry=r.carry, n_iters=left,
+                                arrival_s=rec.finish_s,
+                            )
+                            heapq.heappush(
+                                heap, (rec.finish_s, cont.qid, seq, cont)
+                            )
+                            seq += 1
+                        else:
+                            r.arrival_s = first_arrival[r.qid]
+                            # slices are internal; results are final
+                            r.carry = None
+                            results[r.qid] = r
+                            done.append(r)
+                            series.histogram("query_latency_s").observe(
+                                rec.finish_s, r.latency_s
+                            )
+                    self.metrics.record_queries(done)
             admit()
         # every parked continuation refilled its bucket before the loop
         # could drain (overflow[key] non-empty implies pending[key] was full

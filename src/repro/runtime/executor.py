@@ -233,19 +233,52 @@ class Executor:
         clock: float,
         return_state: bool = False,
     ) -> tuple[list[QueryResult], BatchRecord]:
-        """Execute one microbatch and place it on the pool's timeline.
+        """Execute one microbatch and place it on the pool's timeline:
+        `timed_execute`, then `book`.
 
         Real execution happens now (host order = flush order, replayable);
         the simulated start/finish come from the chosen workers' busy-until
         clocks and the calibrated service prediction."""
-        cfg = self.config
         route = self.batch_route(program, key, qs)
-        width = cfg.shard_width if route == "sharded" else 1
+        batch, measured_s, lowered = self.timed_execute(
+            program, key, qs, route, return_state
+        )
+        rec = self.book(program, key, qs, route, clock, batch, measured_s,
+                        lowered)
+        return batch, rec
+
+    def timed_execute(
+        self,
+        program,
+        key: BucketKey,
+        qs: list[Query],
+        route: str,
+        return_state: bool = False,
+    ) -> tuple[list[QueryResult], float, int]:
+        """`execute`, its wall seconds (what the calibrator learns) and the
+        clamp-set lowerings it caused."""
         lower0 = program.clamp_lowerings
         # measured_s feeds the calibrator; it is real time by design
         wall0 = time.perf_counter()  # lint: allow[wallclock-in-sim]
         batch = self.execute(program, key, qs, route, return_state)
         measured_s = time.perf_counter() - wall0  # lint: allow[wallclock-in-sim]
+        return batch, measured_s, program.clamp_lowerings - lower0
+
+    def book(
+        self,
+        program,
+        key: BucketKey,
+        qs: list[Query],
+        route: str,
+        clock: float,
+        batch: list[QueryResult],
+        measured_s: float,
+        clamp_lowerings: int = 0,
+    ) -> BatchRecord:
+        """Place an executed microbatch on the pool's simulated timeline:
+        predict its service time, book the earliest-free workers, stamp
+        the results' start/finish, and return its `BatchRecord`."""
+        width = self.config.shard_width if route == "sharded" else 1
         n_padded = batcher_mod.pad_size(len(qs), self.pad_sizes)
         service_s, service_src = self.calibrator.predict(
             program, calibrate_mod.sig_of(key, route), n_padded,
@@ -264,15 +297,14 @@ class Executor:
                 n_padded=n_padded, service_s=service_s,
                 service_src=service_src, measured_s=measured_s,
             )
-        rec = BatchRecord(
+        return BatchRecord(
             model=qs[0].model, kind=key.kind, n_real=len(qs),
             n_padded=n_padded, service_s=service_s,
-            clamp_lowerings=program.clamp_lowerings - lower0,
+            clamp_lowerings=clamp_lowerings,
             worker=workers[0], n_workers=len(workers), route=route,
             start_s=start, finish_s=finish, measured_s=measured_s,
             service_src=service_src,
         )
-        return batch, rec
 
     # -- tracing ------------------------------------------------------------
 

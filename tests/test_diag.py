@@ -204,27 +204,6 @@ def test_engine_quality_briefs_and_metrics_rollup():
     assert "rhat max" in eng.metrics.table()
 
 
-def test_engine_emits_quality_trace_instants():
-    from repro.obs import tracer
-
-    clear_program_cache()
-    tracer.enable()
-    try:
-        eng = Engine({"survey": bn_repository_replica("survey")},
-                     EngineConfig(pad_sizes=(4,), max_batch=4,
-                                  diagnostics=True))
-        eng.submit([Query(qid=i, model="survey", n_chains=8, n_iters=20,
-                          burn_in=5) for i in range(2)])
-        eng.run()
-        evs = [e for e in tracer.get().events if e.name == "quality"]
-    finally:
-        tracer.disable()
-    assert len(evs) == 2
-    for e in evs:
-        assert e.cat == "quality"
-        assert {"qid", "model", "rhat_max", "ess_min"} <= set(e.args)
-
-
 def test_resume_without_quality_carry_raises():
     prog = compile_graph(bn_repository_replica("survey"))
     _, _, st = prog.run(key=jax.random.key(1), n_chains=4, n_iters=10,

@@ -164,17 +164,24 @@ def test_event_counts_reconcile_with_metrics():
         [round(b.service_s, 12) for b in recs]
     # kernel entry spans (bn_rounds/mrf_rounds host entries — here reached
     # via the first-lowering cross-checks; bucket dispatches enter through
-    # execute_bucket instead)
+    # the batcher's batch/* spans instead)
     kernels = [e for e in dicts if e["cat"] == "kernel"]
     assert kernels
     assert {e["name"] for e in kernels} <= {"bn_rounds", "mrf_rounds"}
-    # batcher pad decisions on every vmap dispatch
-    buckets = [e for e in dicts if e["name"] == "execute_bucket"]
-    vmap_recs = [b for b in m.batch_records if b.route == "vmap"]
-    assert len(buckets) == len(vmap_recs)
-    for e in buckets:
+    # pad decisions on every dispatch's wall span, one per BatchRecord
+    wall = [e for e in dicts if e["name"] == "engine/dispatch"]
+    assert len(wall) == len(m.batch_records)
+    for e, b in zip(wall, m.batch_records):
         assert 0.0 < e["args"]["pad_efficiency"] <= 1.0
-        assert e["args"]["n_real"] <= e["args"]["n_padded"]
+        assert (e["args"]["n_real"], e["args"]["n_padded"]) == (
+            b.n_real, b.n_padded)
+        assert (e["args"]["model"], e["args"]["route"]) == (b.model, b.route)
+    # the batcher's launch on every vmap dispatch, under its number
+    launches = [e for e in dicts if e["name"] == "batch/launch"]
+    vmap_recs = [b for b in m.batch_records if b.route == "vmap"]
+    assert len(launches) == len(vmap_recs)
+    assert {e["args"]["dispatch"] for e in launches} <= {
+        e["args"]["dispatch"] for e in wall}
 
 
 def test_run_start_declares_worker_lanes():

@@ -14,6 +14,7 @@ compiled for a described chip cannot be read back without one).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -123,6 +124,39 @@ def test_fused_gibbs_sweep_compiles(one_chip, model, sampler):
         _on(one_chip, (32, cbn.n_nodes), jnp.int32),
         _on(one_chip, key.shape, key.dtype),
     )
+    assert MOSAIC_CALL in text
+
+
+def test_fused_bn_bucket_names_its_kernel(one_chip):
+    """The serving path's fused BN bucket executable (`_bn_bucket`, vmapped
+    over query lanes) holds the Mosaic kernel as a custom call named
+    `bn_gibbs_kernel` (the `pallas_call`'s name) — a name a device trace
+    reads the same after any change to the surrounding HLO."""
+    from repro.compile import compile_graph, ir as ir_mod
+    from repro.core.graphs import bn_repository_replica
+    from repro.kernels import bn_gibbs
+    from repro.runtime import batcher
+
+    graph = ir_mod.canonicalize(bn_repository_replica("alarm"),
+                                evidence_mode="runtime")
+    program = compile_graph(graph)
+    query = batcher.Query(qid=0, model="alarm", evidence={0: 1},
+                          n_chains=32, n_iters=4, burn_in=1)
+    key = batcher.bucket_key(query, graph, "schedule", fused=True)
+    assert key.fused
+    groups = program.clamped_executable(key.clamp_nodes, key.backend)
+    n = program.ir.n_nodes
+    text = batcher._bn_bucket.lower(
+        program.cbn, groups, _on(one_chip, (2, n), jnp.int32),
+        _on(one_chip, (n,), jnp.bool_), _on(one_chip, (2,), jnp.uint32),
+        None, None, n_chains=32, n_iters=4, burn_in=1, thin=1,
+        sampler="lut_ky", return_state=True, fused=True, interpret=False,
+    ).compile().as_text()
+    name = bn_gibbs.KERNEL_NAME
+    assert name == "bn_gibbs_kernel"
+    calls = re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = [^\n]* custom-call\(",
+                       text, re.M)
+    assert any(c.startswith(name) for c in calls), calls
     assert MOSAIC_CALL in text
 
 
