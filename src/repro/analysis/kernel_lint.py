@@ -10,16 +10,27 @@ unfused route up front (`fused_fits`), and the CLI can flag wide replicas
 
 The estimate mirrors the kernels' actual buffer structure:
 
-  * **BN** (`kernels.bn_gibbs.fused_gibbs_sweep`): the VMEM-resident
-    inputs (value block ×2, per-round gather tensors at the padded
-    (c_max, f_max, s_max) envelope, the round's random words, the whole
-    log-CPT arena, the exp LUT) plus the kernel's live intermediates,
-    dominated by the `val_or_v` candidate tensor — (B, C, F, S, V) × 4
-    bytes — and the (B, C, F, V) address/gather pair.  The envelope is
-    re-derived here numpy-only: DSATUR over the IR's moral adjacency
-    gives c_max (bit-identical to `DsaturPass`; `MergeSmallColorsPass`
-    is the identity on DSATUR output, so the runtime pipeline matches
-    too), and f_max/s_max are coloring-independent structural maxima.
+  * **BN** (`kernels.bn_gibbs.fused_gibbs_sweep`): the kernel's planar
+    layout.  A plane is one (b_pad, c_pad) int32 array — chains on
+    sublanes padded to 8, a round's nodes on lanes padded to whole
+    128-lane tiles — and the chain state is one (b_pad, n_pad) value
+    block.  Inputs are the pipelined blocks, double-buffered: the value
+    block in and out, one round's tables ((F + 3 F S) rows of c_pad lanes,
+    rows padded to 8) and inverse node map, its W random-word planes, the
+    whole log-CPT arena as (rows, 128) and the exp LUT.  Intermediates
+    are the planes that live across the kernel's loops: the chain values
+    at every (factor, scope) slot, one log-prob plane per candidate value
+    plus the gather's address and accumulator, and the KY draw stage — V
+    weight planes, V + 1 preprocessed planes, the word planes and the
+    walk's carry — plus the value block's state and merge.  The
+    per-candidate values (`val_or_v`, addresses, arena reads) are built
+    plane by plane inside the (v, f, s) loops and never held whole.  The
+    envelope is re-derived here numpy-only: DSATUR over the IR's moral
+    adjacency gives c_max (bit-identical to `DsaturPass`;
+    `MergeSmallColorsPass` is the identity on DSATUR output, so the
+    runtime pipeline matches too), and f_max/s_max are
+    coloring-independent structural maxima.  A clamped executable's
+    rounds are subsets of these, so the unclamped envelope bounds them.
   * **MRF** (`kernels.mrf_gibbs.mrf_half_step_kernel`): one row-block
     tile — 3 label blocks + evidence + words + the per-candidate energy
     stack and (site, LANES) draw-stage buffers — times the chain count
@@ -28,9 +39,13 @@ The estimate mirrors the kernels' actual buffer structure:
     whole chain width resident at once to stay conservative).
 
 Estimates are deliberately *upper-ish* bounds, not bit-accurate sums:
-Mosaic's scratch allocation and double-buffering are not modeled, so the
-headroom factor below absorbs them.  The point is to demote buckets that
-are clearly over budget, not to pack VMEM to the last byte.
+Mosaic's scratch allocation is not modeled (nor, for the MRF, the
+pipeline's double buffers), so the headroom factor below absorbs it.
+For BN buckets of 8 to 512 chains the estimate lies 1.3 to 2.2 times
+above the smallest scoped VMEM limit Mosaic compiles the kernel under
+(`tests/test_tpu_compile.py` holds it above that limit for served
+buckets).  The point is to demote buckets that are clearly over budget,
+not to pack VMEM to the last byte.
 """
 
 from __future__ import annotations
@@ -56,6 +71,14 @@ KY_LANES = 128
 # Mirrors bayesnet.build_exp_weight_lut defaults (paper Sec. III-D).
 EXP_LUT_SIZE = 16
 ITEM_BYTES = 4  # int32 / float32 throughout both kernels
+# Rows of one (8, 128) vreg tile: a table's rows and the chains pad to it.
+SUBLANES = 8
+# BN kernel planes besides the per-candidate log-probs in the flat-CPT
+# gather (address, factor sum, the arena loop's carry and tile pick), and
+# besides the bins in the KY walk (its carry: distance, level, label, done
+# flag, bit and rejection counters).
+_GATHER_PLANES = 4
+_WALK_PLANES = 6
 
 _VMEM_BUDGET = DEFAULT_VMEM_BYTES
 
@@ -165,12 +188,20 @@ def _ky_words(v: int, sampler: str, precision: int = 16,
     return -(-(precision * max_retries) // 32)
 
 
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
 def bn_fused_footprint(
     graph, n_chains: int, sampler: str = "lut_ky", shard_width: int = 1
 ) -> KernelFootprint:
     """Estimate `fused_gibbs_sweep`'s per-core VMEM residency for one
     model at one chain width (the batcher vmaps buckets over query lanes,
     which batches the *grid*, so per-step residency stays one lane's).
+
+    Every buffer is counted in the kernel's planar layout: a plane is one
+    (b_pad, c_pad) int32 array — chains padded to 8 sublanes, the round's
+    nodes to whole 128-lane tiles — and the value block is (b_pad, n_pad).
 
     `shard_width > 1` models the sharded fused engine
     (`distributed.bn_fused_sharded`): each device's round kernel sees only
@@ -179,27 +210,36 @@ def bn_fused_footprint(
     the value block, CPT arena, and LUT stay fully resident (the psum
     merge needs whole-state values on every device)."""
     b = int(n_chains)
-    n = graph.n_nodes
+    b_pad = _round_up(b, SUBLANES)
+    n_pad = _round_up(graph.n_nodes, KY_LANES)
     c, f, s = bn_group_envelope(graph)
     c = -(-c // max(1, int(shard_width)))  # per-device owned slice
+    c_pad = _round_up(c, KY_LANES)
     v = max(graph.cards) if graph.cards else 0
     w = _ky_words(v, sampler)
-    arena = _bn_arena_size(graph.source)
+    arena_rows = -(-_bn_arena_size(graph.source) // KY_LANES)
+    plane = b_pad * c_pad
+    block = b_pad * n_pad
+    # pipelined operands are double-buffered; a table's rows pad to whole
+    # 8-row sublane tiles
     inputs = {
-        "value_block": 2 * b * n,  # vals_ref + resident out_ref
-        "round_tensors": 2 * c + c * f + 3 * c * f * s,
-        "random_words": b * c * w,
-        "cpt_arena": arena,
-        "exp_lut": EXP_LUT_SIZE,
+        "value_block": 2 * 2 * block,  # vals_ref + resident out_ref
+        # base (F rows), stride, scope_var, is_self (F S rows each), cards
+        "round_tables": 2 * (_round_up(f, SUBLANES)
+                             + 3 * _round_up(f * s, SUBLANES)
+                             + SUBLANES) * c_pad,
+        "inverse_map": 2 * SUBLANES * n_pad,
+        "random_words": 2 * w * plane,
+        "cpt_arena": 2 * _round_up(arena_rows, SUBLANES) * KY_LANES,
+        "exp_lut": 2 * SUBLANES * KY_LANES,
     }
     inter = {
-        "scope_vals": b * c * f * s,
-        "val_or_v": b * c * f * s * v,  # the dominant candidate tensor
-        "gather_addr": b * c * f * v,
-        "gather_read": b * c * f * v,
-        "logp": 3 * b * c * v,  # logp + flat + z
-        "ky_weights": 3 * b * c * KY_LANES,  # w + m_ext + walk state
-        "scatter": c * n + b * n,
+        "scope_vals": f * s * plane,  # chain values at every scope slot
+        "candidates": (v + _GATHER_PLANES) * plane,  # logp + addr/gather
+        # weights (kept for the argmax fallback), the v + 1 preprocessed
+        # bins, the word planes and the walk's carry
+        "ky_planes": (2 * v + 1 + w + _WALK_PLANES) * plane,
+        "value_planes": 4 * block,  # state, inverse map, labels, merge
     }
     breakdown = {k: x * ITEM_BYTES for k, x in {**inputs, **inter}.items()}
     return KernelFootprint(

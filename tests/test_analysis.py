@@ -323,11 +323,13 @@ def test_ky_lanes_constant_pinned_to_kernel():
 
 def test_bn_footprint_scales_with_chains_mrf_does_not():
     pigs = _bn_ir("pigs")
-    wide = kernel_lint.bn_fused_footprint(pigs, 32)
-    narrow = kernel_lint.bn_fused_footprint(pigs, 8)
+    wide = kernel_lint.bn_fused_footprint(pigs, 256)
+    narrow = kernel_lint.bn_fused_footprint(pigs, 32)
     assert wide.total_bytes > narrow.total_bytes
     assert wide.total_bytes > kernel_lint.vmem_budget()  # the demotion story
+    # the planar layout puts pigs' serving width well inside the budget
     assert narrow.total_bytes <= kernel_lint.vmem_budget()
+    assert wide.breakdown["scope_vals"] == 8 * narrow.breakdown["scope_vals"]
     mrf = compile_ir.from_mrf(GridMRF(64, 64, 4))
     a = kernel_lint.mrf_fused_footprint(mrf, 32)
     b = kernel_lint.mrf_fused_footprint(mrf, 1)
@@ -338,7 +340,7 @@ def test_bn_footprint_scales_with_chains_mrf_does_not():
 
 def test_footprint_findings_severity():
     pigs = _bn_ir("pigs")
-    fp = kernel_lint.bn_fused_footprint(pigs, 32)
+    fp = kernel_lint.bn_fused_footprint(pigs, 256)
     demoted = fp.findings()
     assert [f.rule for f in demoted] == ["vmem-budget"]
     assert demoted[0].severity == "warning"  # batcher guard makes it advisory
@@ -356,10 +358,10 @@ def test_batcher_demotes_oversized_fused_bucket():
     from repro.runtime import batcher
 
     g = _bn_ir("pigs")
-    wide = batcher.Query(qid=0, model="pigs", n_chains=32)
+    wide = batcher.Query(qid=0, model="pigs", n_chains=256)
     key = batcher.bucket_key(wide, g, "schedule", fused=True)
-    assert key.fused is False  # ~18.6 MiB estimate vs the 16 MiB budget
-    narrow = batcher.Query(qid=1, model="pigs", n_chains=8)
+    assert key.fused is False  # ~23.7 MiB estimate vs the 16 MiB budget
+    narrow = batcher.Query(qid=1, model="pigs", n_chains=32)
     assert batcher.bucket_key(narrow, g, "schedule", fused=True).fused is True
     # shrink the budget and the same narrow bucket demotes too
     prev = kernel_lint.set_vmem_budget(1 << 16)
@@ -386,8 +388,8 @@ def test_sharded_footprints_budget_the_per_shard_envelope():
     over = sh.findings(budget=1)
     assert over and "@sh4" in over[0].loc  # findings name the slice width
     pigs = _bn_ir("pigs")
-    bn_whole = kernel_lint.bn_fused_footprint(pigs, 32)
-    bn_sh = kernel_lint.bn_fused_footprint(pigs, 32, shard_width=4)
+    bn_whole = kernel_lint.bn_fused_footprint(pigs, 256)
+    bn_sh = kernel_lint.bn_fused_footprint(pigs, 256, shard_width=4)
     assert bn_sh.total_bytes < bn_whole.total_bytes
     # the pigs-class demotion story inverts on a slice: the whole envelope
     # busts the budget, the per-device owned node slice fits
@@ -401,9 +403,10 @@ def test_fused_fits_judges_sharded_buckets_per_shard():
     from repro.runtime import batcher
 
     g = _bn_ir("pigs")
-    assert not kernel_lint.fused_fits(g, 32)
-    assert kernel_lint.fused_fits(g, 32, shard_width=4)
-    wide = batcher.Query(qid=0, model="pigs", n_chains=32)
+    assert not kernel_lint.fused_fits(g, 256)
+    assert kernel_lint.fused_fits(g, 256, shard_width=4)
+    assert kernel_lint.fused_fits(g, 32)
+    wide = batcher.Query(qid=0, model="pigs", n_chains=256)
     assert batcher.bucket_key(wide, g, "schedule", fused=True).fused is False
     assert batcher.bucket_key(
         wide, g, "schedule", fused=True, shard_width=4

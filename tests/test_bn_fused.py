@@ -71,6 +71,24 @@ def test_fused_sweep_wide_cards_bit_exact():
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(fus))
 
 
+def test_fused_sweep_two_lane_tiles_bit_exact():
+    """A colour round wider than 128 nodes lays its nodes on two lane tiles
+    and the 300-node value block on three: every cross-tile gather (scope
+    values, the labels back through the inverse map) picks its tile by the
+    index's high bits, as on pigs."""
+    bn = random_bayesnet(300, max_parents=2, cards=3, seed=5)
+    cbn = bnet.compile_bayesnet(bn)
+    fr = bn_gibbs.build_fused_rounds(cbn.groups)
+    assert fr.c_max > bn_gibbs.LANES
+    assert fr.cards.shape[-1] == 2 * bn_gibbs.LANES
+    vals, _ = bnet.init_chain_values(cbn, jax.random.key(2), 2)
+    key = jax.random.key(29)
+    ref = bnet.gibbs_sweep(cbn, vals, key, "lut_ky")
+    fus = bn_gibbs.fused_gibbs_sweep(cbn, fr, vals, key, "lut_ky",
+                                     interpret=True)
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(fus))
+
+
 # ---------------------------------------------------------------------------
 # Program-level matrix: fused vs both unfused backends, clamps, slices
 # ---------------------------------------------------------------------------

@@ -160,6 +160,61 @@ def test_fused_bn_bucket_names_its_kernel(one_chip):
     assert MOSAIC_CALL in text
 
 
+@pytest.mark.parametrize("model", ["hepar2", "pigs"])
+def test_bn_footprint_bounds_mosaic_on_served_buckets(one_chip, monkeypatch,
+                                                      model):
+    """`kernel_lint.bn_fused_footprint` is an upper bound of what Mosaic
+    allocates: the benchmark's served fused bucket (`bnlearn.long`: 4 query
+    lanes x 32 chains, the traffic's observed set) compiles with the kernel's
+    scoped VMEM limit set to the estimate.  pigs' rounds span two 128-lane
+    node tiles; hepar2's gather the widest scopes."""
+    import json
+    import pathlib
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from bench import generator, harness, models as bench_models
+    from repro.analysis import kernel_lint
+    from repro.compile import compile_graph, ir as ir_mod
+    from repro.core import compat
+    from repro.runtime import batcher
+
+    config = json.loads(
+        (root / "bench/configs/bnlearn-replicas.json").read_text())
+    traffic = json.loads((root / "bench/traffic/long.json").read_text())
+    plain = bench_models.build(config)
+    bn = harness.program_models(config, {model: plain[model]})[model]
+    graph = ir_mod.canonicalize(bn, evidence_mode="runtime")
+    program = compile_graph(graph)
+    observed = generator.observed_nodes(traffic, model, plain[model])
+    n_chains, lanes = traffic["query"]["n_chains"], 4
+    query = batcher.Query(qid=0, model=model, n_chains=n_chains,
+                          evidence={int(j): 0 for j in observed})
+    key = batcher.bucket_key(query, graph, "schedule", fused=True)
+    assert key.fused  # pigs at 32 chains fits the 16 MiB budget
+    limit = kernel_lint.bn_fused_footprint(graph, n_chains).total_bytes
+
+    params, seen = compat.tpu_compiler_params, []
+
+    def limited(**kw):
+        seen.append(limit)
+        return params(vmem_limit_bytes=limit, **kw)
+
+    monkeypatch.setattr(compat, "tpu_compiler_params", limited)
+    groups = program.clamped_executable(key.clamp_nodes, key.backend)
+    n = graph.n_nodes
+    text = batcher._bn_bucket.lower(
+        program.cbn, groups, _on(one_chip, (lanes, n), jnp.int32),
+        _on(one_chip, (n,), jnp.bool_), _on(one_chip, (lanes,), jnp.uint32),
+        None, None, n_chains=n_chains, n_iters=2, burn_in=1, thin=1,
+        sampler="lut_ky", return_state=True, fused=True, interpret=False,
+    ).compile().as_text()
+    assert seen  # the kernel was traced under the estimate's limit
+    assert MOSAIC_CALL in text
+
+
 def test_mrf_fused_sharded_compiles(topo):
     """The one-shard_map-body MRF program on a 2x2 mesh of described chips:
     halo ppermutes between Mosaic half-steps over each device's row slab."""
