@@ -51,9 +51,7 @@ class Reference:
                 self._models[name] = self.module.BNReference(
                     m["cards"], m["parents"], m["cpts"], observed, self.spec)
             else:
-                self._models[name] = self.module.MRFReference(
-                    m["height"], m["width"], m["labels"], m["theta"], m["h"],
-                    self.spec)
+                self._models[name] = self.module.MRFReference(m, self.spec)
         return self._models[name]
 
     def answer(self, q: generator.QuerySpec) -> Answer:

@@ -20,6 +20,7 @@ import zlib
 import numpy as np
 
 from bench import models as models_mod
+from bench import spec
 
 WINDOW, WARMUP = 0, 1  # independent query streams of one seed
 
@@ -35,7 +36,7 @@ class QuerySpec:
     thin: int
     sampler: str
     evidence: dict | None = None  # BN: {node: value}
-    image: np.ndarray | None = None  # MRF: (H, W) noisy observation
+    image: np.ndarray | None = None  # MRF: (H, W) observation
 
 
 def clients(traffic: dict) -> list[str]:
@@ -66,6 +67,18 @@ def site_updates(traffic: dict, config: dict, plain: dict) -> int:
     return total
 
 
+def observation(draw, model: dict, traffic: dict, seed: int) -> np.ndarray:
+    """One observation of an MRF model: int32 (H, W), as the contract of
+    `bench/images/` states."""
+    image = draw(model["height"], model["width"], model["labels"], traffic,
+                 seed)
+    shape = (model["height"], model["width"])
+    if image.dtype != np.int32 or image.shape != shape:
+        raise ValueError(f"an observation must be int32 of shape {shape}; "
+                         f"got {image.dtype} {image.shape}")
+    return image
+
+
 class Stream:
     """Rounds of queries drawn from (seed, stream) in order: round k is the
     same whatever number of rounds is drawn."""
@@ -83,10 +96,10 @@ class Stream:
             if config["kind"] == "bn":
                 self.observed[name] = observed_nodes(traffic, name, m)
             else:
+                draw = spec.image_drawer(m.get("image", "label_noise"))
                 self.images[name] = [
-                    models_mod.denoising_image(
-                        m["height"], m["width"], m["labels"],
-                        traffic["noise"], int(self.rng.integers(1 << 16)))
+                    observation(draw, m, traffic,
+                                int(self.rng.integers(1 << 16)))
                     for _ in range(traffic["image_pool"])
                 ]
 

@@ -150,7 +150,7 @@ def program_models(config: dict, plain: dict) -> dict:
                 list(m["cpts"]), name=name)
         else:
             out[name] = GridMRF(m["height"], m["width"], m["labels"],
-                                theta=m["theta"], h=m["h"], name=name)
+                                name=name, **models_mod.mrf_fields(m))
     return out
 
 

@@ -5,10 +5,10 @@ gives the published node count, arc count and largest in-degree, which
 the replica has exactly; which nodes the arcs join, the arities and the
 CPT rows (Dirichlet(0.8) draws) are drawn from the entry's seed, since the
 real networks are not available offline (an assumption every
-configuration file names).  MRF: Potts denoising grids with
-piecewise-constant images under label noise.  Both generators are fixed
-copies, so a change to the program's own generators cannot move the
-benchmark.
+configuration file names).  MRF: the entry itself, the energy the
+configuration states (`bench/spec.py`); observations are drawn by
+`bench/images/`.  The generators are fixed copies, so a change to the
+program's own generators cannot move the benchmark.
 """
 
 from __future__ import annotations
@@ -55,19 +55,13 @@ def random_bayesnet(n_nodes: int, n_arcs: int, max_in_degree: int, arities,
             "cpts": cpts}
 
 
-def denoising_image(height: int, width: int, n_labels: int, noise: float,
-                    seed: int) -> np.ndarray:
-    """A piecewise-constant image of rectangles with each pixel replaced by
-    a uniform label with probability `noise`: the noisy observation."""
-    rng = np.random.default_rng(seed)
-    clean = np.zeros((height, width), np.int32)
-    for _ in range(max(3, n_labels)):
-        r0, c0 = rng.integers(0, height), rng.integers(0, width)
-        rh, cw = rng.integers(height // 4, height), rng.integers(width // 4, width)
-        clean[r0:r0 + rh, c0:c0 + cw] = rng.integers(0, n_labels)
-    flip = rng.random((height, width)) < noise
-    noisy = np.where(flip, rng.integers(0, n_labels, (height, width)), clean)
-    return noisy.astype(np.int32)
+# Keys of an MRF entry that are not fields of the program's `GridMRF`.
+MRF_SHAPE = ("height", "width", "labels", "image")
+
+
+def mrf_fields(entry: dict) -> dict:
+    """The energy's fields of an MRF entry, by the program's names."""
+    return {k: v for k, v in entry.items() if k not in MRF_SHAPE}
 
 
 def build(config: dict) -> dict[str, dict]:

@@ -1,15 +1,21 @@
-"""Reference checkerboard Gibbs on a Potts grid MRF (free boundary, Potts
-data term): what each served denoising query must equal, bit for bit.
+"""Reference checkerboard Gibbs on a grid MRF with Potts smoothness and
+a free boundary: what each served query must equal, bit for bit.
 
-Semantics of one query (seed s, noisy image e):
+The configuration's model entry is the energy (`bench/spec.py`): `height`,
+`width`, `labels`, `theta`, `h`, and `data_cost` (default `potts`); `image`
+names the observation module and is not part of the energy.  A key this
+module does not implement is refused, so no part of an energy is ignored.
+
+Semantics of one query (seed s, observation e):
   * key = PRNG key of s; (k0, key) = split(key); labels start at
     randint(k0, (chains, H, W), 0, V);
   * each sweep: keys = split(key, 3); key = keys[0]; the two colors of the
     grid's DSATUR coloring in color order, color i with keys[1 + i]: every
     site of that parity draws from
-        log P(l = v | neighbors, e) = theta * #(4-neighbors at v)
-                                      + h * [e == v]   (float32)
-    while the other parity keeps its labels.
+        log P(l = v | neighbors, e) = theta * #(4-neighbors at v) + D(v, e)
+    (float32), D = h * [e == v] (`potts`) or -h * (e - v)^2 (`quadratic`,
+    the difference cast to float32, then (-h * d) * d), while the other
+    parity keeps its labels.
 """
 
 from __future__ import annotations
@@ -22,11 +28,24 @@ from bench.reference import coloring
 from bench.reference.sampler import DrawSpec, draw
 
 
+IMPLEMENTED = frozenset(
+    {"height", "width", "labels", "image", "theta", "h", "data_cost"})
+DATA_COSTS = ("potts", "quadratic")
+
+
 class MRFReference:
-    def __init__(self, height: int, width: int, n_labels: int, theta: float,
-                 h: float, spec: DrawSpec):
-        self.height, self.width, self.v = height, width, n_labels
-        self.theta, self.h, self.spec = float(theta), float(h), spec
+    def __init__(self, entry: dict, spec: DrawSpec):
+        unknown = sorted(set(entry) - IMPLEMENTED)
+        if unknown:
+            raise ValueError(f"the MRF reference does not implement {unknown}")
+        self.data_cost = entry.get("data_cost", "potts")
+        if self.data_cost not in DATA_COSTS:
+            raise ValueError(f"the MRF reference does not implement "
+                             f"data_cost {self.data_cost!r}")
+        height, width = entry["height"], entry["width"]
+        self.height, self.width, self.v = height, width, entry["labels"]
+        self.theta, self.h = float(entry["theta"]), float(entry["h"])
+        self.spec = spec
         colors = coloring.dsatur(coloring.grid_adjacency(height, width))
         parity = (np.arange(height)[:, None] + np.arange(width)[None]) % 2
         self.masks = [
@@ -50,7 +69,11 @@ class MRFReference:
             + jnp.concatenate([z_col, onehot[:, :, :-1]], axis=2)
             + jnp.concatenate([onehot[:, :, 1:], z_col], axis=2)
         )
-        data = self.h * (image[..., None] == values).astype(jnp.float32)
+        if self.data_cost == "potts":
+            data = self.h * (image[..., None] == values).astype(jnp.float32)
+        else:
+            diff = (image[..., None] - values).astype(jnp.float32)
+            data = -self.h * diff * diff
         return self.theta * counts + data
 
     def _sample(self, image, seed, *, n_chains, n_iters):

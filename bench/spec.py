@@ -1,8 +1,27 @@
 """Finding a cell's parts by name: `BENCHMARK.json` names the cell, its
 configuration file and its metrics; a traffic mix `<mix>` lives in
-`bench/traffic/<mix>.json` and a per-layer metric `<metric>` is read by
-`bench/metrics/<metric>.py`.  Adding a cell, mix or metric adds files and
-entries; nothing here changes."""
+`bench/traffic/<mix>.json`, a per-layer metric `<metric>` is read by
+`bench/metrics/<metric>.py`, and an MRF observation `<image>` is drawn by
+`bench/images/<image>.py`.  Adding a cell, mix, metric or MRF energy adds
+files and entries; nothing here changes.
+
+A configuration file names its `kind` (`bn` or `mrf`), its `reference`
+module (`bench/reference/<reference>.py`) and its `models`.  An MRF model
+entry is the energy:
+
+  * `height`, `width` and `labels` are the grid's shape;
+  * `image` names the observation module (default `label_noise`), whose
+    `draw(height, width, labels, traffic, seed)` returns an int32 (H, W)
+    observation;
+  * every other key is a field of the program's `GridMRF`, passed by name
+    (`theta`, `h`, `data_cost`); a field the program lacks fails set-up
+    with the program's own error, before anything is compiled;
+  * the configuration's reference module takes the entry whole,
+    `MRFReference(entry, spec)`, and refuses with `ValueError` any key it
+    does not implement.
+
+So a new MRF energy is a configuration file, a reference module and, if
+its observations are new, an observation module."""
 
 from __future__ import annotations
 
@@ -50,11 +69,22 @@ def load_cell(root: pathlib.Path, name: str) -> Cell:
     )
 
 
-def metric_reader(name: str, directory: pathlib.Path = BENCH / "metrics"):
-    """The `read(ctx)` function of a per-layer metric's own module."""
+def _module(directory: pathlib.Path, name: str):
     path = directory / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
-        f"bench.metrics.{name.replace('-', '_').replace('.', '_')}", path)
+        f"bench.{directory.name}.{name.replace('-', '_').replace('.', '_')}",
+        path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def metric_reader(name: str, directory: pathlib.Path = BENCH / "metrics"):
+    """The `read(ctx)` function of a per-layer metric's own module."""
+    return _module(directory, name).read
+
+
+def image_drawer(name: str):
+    """The `draw(height, width, labels, traffic, seed)` function of an MRF
+    observation's own module."""
+    return _module(BENCH / "images", name).draw

@@ -5,6 +5,7 @@ profiler traces, and the refusal to run without a TPU."""
 from __future__ import annotations
 
 import gzip
+import hashlib
 import json
 import os
 import pathlib
@@ -112,6 +113,31 @@ def test_same_seed_same_stream(name):
     warm = generator.Stream(cell.traffic, cell.config, plain, seed,
                             generator.WARMUP).round()
     assert {q.qid for q in warm}.isdisjoint(q.qid for q in flat)
+
+
+def test_mrf_stream_is_pinned():
+    """`tiny.mrf`'s observations and chain seeds, bit for bit: a change to
+    how `bench/images/` draws them shows here."""
+    cell = spec.load_cell(DATA / "cells", "tiny.mrf")
+    plain = models.build(cell.config)
+    h = hashlib.sha256()
+    for stream in (generator.WINDOW, generator.WARMUP):
+        for r in generator.Stream(cell.traffic, cell.config, plain,
+                                  2**31 + 977, stream).rounds(3):
+            for q in r:
+                h.update(f"{q.qid} {q.model} {q.seed} {q.n_chains} "
+                         f"{q.n_iters};".encode())
+                h.update(q.image.astype("<i4").tobytes())
+    assert h.hexdigest() == (
+        "6e87a2cc98b9e3918632e27f6369e6fb616cf60cf62b5920dfa73204799d7fdc")
+
+
+@pytest.mark.parametrize("image", [np.zeros((8, 8), np.int64),
+                                   np.zeros((8, 7), np.int32)])
+def test_observation_of_the_wrong_type_or_shape_is_refused(image):
+    entry = {"height": 8, "width": 8, "labels": 3}
+    with pytest.raises(ValueError, match="int32"):
+        generator.observation(lambda *a: image, entry, {}, seed=0)
 
 
 def test_site_updates_counted_from_traffic():
