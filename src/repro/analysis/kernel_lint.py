@@ -31,16 +31,21 @@ The estimate mirrors the kernels' actual buffer structure:
     runtime pipeline matches too), and f_max/s_max are
     coloring-independent structural maxima.  A clamped executable's
     rounds are subsets of these, so the unclamped envelope bounds them.
-  * **MRF** (`kernels.mrf_gibbs.mrf_half_step_kernel`): one row-block
-    tile — 3 label blocks + evidence + words + the per-candidate energy
-    stack and (site, LANES) draw-stage buffers — times the chain count
-    (the chain vmap batches the grid, so each grid step still holds one
-    chain's tile; chains share nothing, and we budget for the batcher's
-    whole chain width resident at once to stay conservative).
+  * **MRF** (`kernels.mrf_gibbs.mrf_half_step_kernel`): the kernel's
+    planar layout too.  A plane is one (block_h, W) array, block_h the
+    kernel's own `kernels.mrf_tiles.tile_rows` and W padded to whole
+    128-lane tiles.  Inputs are the pipelined blocks, double-buffered:
+    three label blocks, evidence, the output, the word planes, the exp
+    LUT, the two outermost neighbor rows and the phase.  Intermediates are
+    the VMEM scratch — one energy and one weight plane per label plus the
+    rejection bin — and the planes that live across the per-label loops:
+    the tile, its four neighbor shifts, evidence, the running maximum,
+    the loaded words and the walk's carry.  The chain vmap batches the
+    grid, so each grid step holds one chain's tile whatever the chains.
 
 Estimates are deliberately *upper-ish* bounds, not bit-accurate sums:
-Mosaic's scratch allocation is not modeled (nor, for the MRF, the
-pipeline's double buffers), so the headroom factor below absorbs it.
+Mosaic's own scratch allocation is not modeled, so the headroom factor
+below absorbs it.
 For BN buckets of 8 to 512 chains the estimate lies 1.3 to 2.2 times
 above the smallest scoped VMEM limit Mosaic compiles the kernel under
 (`tests/test_tpu_compile.py` holds it above that limit for served
@@ -56,6 +61,7 @@ import numpy as np
 
 from repro.analysis import Finding
 from repro.core import coloring as coloring_mod
+from repro.kernels import mrf_tiles
 
 # Per-core VMEM on current TPUs (see /opt/skills/guides: ~16 MiB/core).
 DEFAULT_VMEM_BYTES = 16 * 2**20
@@ -251,41 +257,46 @@ def bn_fused_footprint(
 
 
 def mrf_fused_footprint(
-    graph, n_chains: int, sampler: str = "lut_ky", block_h: int = 32,
-    shard_width: int = 1,
+    graph, n_chains: int, sampler: str = "lut_ky", shard_width: int = 1,
 ) -> KernelFootprint:
     """Estimate `mrf_half_step_kernel`'s residency for one model.  Chains
     (and bucket lanes) are vmapped over the kernel, which batches the
     *grid* — grid steps execute sequentially, so per-step residency is one
     chain's (block_h, W) tile regardless of `n_chains` (kept in the record
-    for the fit-cache key and the report).
+    for the fit-cache key and the report).  block_h is the kernel's own
+    tile rows (`mrf_tiles.tile_rows`).
 
     `shard_width > 1` models the sharded fused engine
     (`distributed.mrf_fused_sharded` via `mrf_halo_half_step_kernel`):
     each device tiles its *local row slab* of height // shard_width rows,
-    with the two ppermute'd halo rows and the traced row offset resident
-    beside the tile."""
+    with the two ppermute'd halo rows resident beside the tile."""
     b = int(n_chains)
     mrf = graph.source
     height, width = int(mrf.height), int(mrf.width)
     h_loc = -(-height // max(1, int(shard_width)))
-    bh = min(block_h, h_loc)
     v = int(mrf.n_labels)
-    sites = bh * width
+    bh = mrf_tiles.tile_rows(h_loc, width, v)
+    w_pad = _round_up(width, KY_LANES)
+    plane = bh * w_pad
+    row = SUBLANES * w_pad  # a (1, W) block pads to 8 sublanes
     w = _ky_words(v, sampler)
+    # the outermost neighbor rows and the (1, 1) phase: -1 edge rows on
+    # one device, the ppermute'd halo rows of a sharded slab
+    edge = "halo_rows" if shard_width > 1 else "edge_rows"
     inputs = {
-        "label_blocks": 4 * sites,  # prev/cur/next halo blocks + out
-        "evidence_block": sites,
-        "random_words": sites * w,
-        "exp_lut": EXP_LUT_SIZE,
+        # prev/cur/next label blocks, evidence and the output
+        "label_blocks": 2 * 5 * plane,
+        "random_words": 2 * w * plane,
+        "exp_lut": 2 * SUBLANES * KY_LANES,
+        edge: 2 * (2 * row + SUBLANES * KY_LANES),
     }
-    if shard_width > 1:
-        # the slab-edge halo rows + the (1, 1) row-offset ref
-        inputs["halo_rows"] = 2 * width + 1
     inter = {
-        "neighbor_shifts": 4 * sites,
-        "energies": (2 * v + 1) * sites,  # energies + z columns + e_max
-        "ky_weights": 3 * sites * KY_LANES,  # w + m_ext + walk state
+        # energies (V), weights and the rejection bin (V + 1): the scratch
+        "label_planes": (2 * v + 1) * plane,
+        # the tile, its four neighbor shifts, evidence, the running max
+        "tile_planes": 7 * plane,
+        # the loaded words, the walk's carry and its prefix sum and index
+        "walk_planes": (w + _WALK_PLANES + 2) * plane,
     }
     breakdown = {k: x * ITEM_BYTES for k, x in {**inputs, **inter}.items()}
     return KernelFootprint(

@@ -455,6 +455,15 @@ _CHECK_CHAINS = 2
 _CHECK_ITERS = 3
 
 
+def _check_evidence(mrf: GridMRF) -> jax.Array:
+    """A fixed observation for the MRF checks: every value of the label
+    alphabet somewhere, and every fifth pixel unobserved (-1)."""
+    r = jnp.arange(mrf.height, dtype=jnp.int32)[:, None]
+    c = jnp.arange(mrf.width, dtype=jnp.int32)[None, :]
+    site = r * mrf.width + c
+    return jnp.where(site % 5 == 0, -1, (7 * r + 3 * c) % mrf.n_labels)
+
+
 def cross_check(program, ex=None) -> None:
     """Run both backends on a tiny budget and require identical bits.
 
@@ -477,7 +486,7 @@ def cross_check(program, ex=None) -> None:
         ).all()
     else:
         mrf = program.mrf
-        ev = jnp.zeros((mrf.height, mrf.width), jnp.int32)
+        ev = _check_evidence(mrf)
         pin_mask = pin_vals = None
         if program.ir.evidence:  # baked pins bind the eager side too
             pin_mask, pin_vals = pin_arrays(mrf, program.ir.evidence)
@@ -561,7 +570,7 @@ def cross_check_fused(
                 )
         return
     mrf = program.mrf
-    ev = jnp.zeros((mrf.height, mrf.width), jnp.int32)
+    ev = _check_evidence(mrf)
     pin_mask = pin_vals = None
     if program.ir.evidence:  # baked pins bind the eager side too
         pin_mask, pin_vals = pin_arrays(mrf, program.ir.evidence)

@@ -100,11 +100,11 @@ class SamplingGraph:
                 )
         else:
             m = self.source
-            _hash_field(
-                h, "mrf",
-                f"{m.height},{m.width},{m.n_labels},{m.theta!r},"
-                f"{m.h!r},{m.data_cost}".encode(),
-            )
+            energy = (f"{m.height},{m.width},{m.n_labels},{m.theta!r},"
+                      f"{m.h!r},{m.data_cost}")
+            if m.pairwise != "potts":  # Potts keys stay as they were
+                energy += f",{m.pairwise},{m.truncation!r}"
+            _hash_field(h, "mrf", energy.encode())
         return h.hexdigest()
 
 

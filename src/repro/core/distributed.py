@@ -76,16 +76,10 @@ def _local_half_step(
     right = jnp.concatenate([lab[..., :, 1:], neg_col], axis=-1)
 
     v_range = jnp.arange(mrf.n_labels, dtype=lab.dtype)
-    cnt = sum(
-        (nb[..., None] == v_range).astype(jnp.float32)
-        for nb in (up, down, left, right)
+    logp = mrf_mod.log_potentials(
+        [nb[..., None] for nb in (up, down, left, right)], ev[..., None],
+        v_range, **mrf_mod.energy_fields(mrf),
     )
-    if mrf.data_cost == "potts":
-        data = mrf.h * (ev[..., None] == v_range).astype(jnp.float32)
-    else:
-        diff = (ev[..., None] - v_range).astype(jnp.float32)
-        data = -mrf.h * diff * diff
-    logp = mrf.theta * cnt + data
     new = draw_from_logits(logp, key, sampler, exp_table, exp_spec)
 
     h_loc, w = lab.shape[-2], lab.shape[-1]
@@ -113,7 +107,15 @@ def mrf_gibbs_sharded(
     sharded over `chain_axes`.  Returns final labels (B, H, W).  `parities`
     is the per-round checkerboard order — (0, 1) eagerly, or the compiled
     `Schedule`'s round order under the schedule backend; each round's halo
-    read is the `ppermute_halo` comm op lowered to `lax.ppermute`."""
+    read is the `ppermute_halo` comm op lowered to `lax.ppermute`.
+
+    Potts smoothness only: other pairwise families serve through the
+    fused sharded engine (`mrf_fused_sharded`)."""
+    if mrf.pairwise != "potts":
+        raise ValueError(
+            f"mrf_gibbs_sharded implements Potts smoothness only, not "
+            f"pairwise={mrf.pairwise!r}; use mrf_fused_sharded"
+        )
     exp_table, exp_spec = build_exp_weight_lut()
     n_grid = int(np.prod([mesh.shape[a] for a in (grid_axis,)]))
     assert mrf.height % n_grid == 0, "grid rows must divide over devices"

@@ -137,13 +137,42 @@ def bn_repository_names() -> list[str]:
     return list(_BN_REPO_STATS)
 
 
+PAIRWISE = ("potts", "truncated_quadratic")
+DATA_COSTS = ("potts", "quadratic")
+
+
 @dataclasses.dataclass(frozen=True)
 class GridMRF:
-    """Potts/Ising MRF on an (H, W) 4-connected grid (paper Eqn. 7).
+    """Pairwise MRF on an (H, W) 4-connected grid with a free boundary
+    (paper Eqn. 7).  The log-potential of site p at value v, given its
+    neighbors' labels l_q and its observation e_p:
 
-    E(l) = sum_(i~j) theta·[l_i == l_j] + sum_i datacost(l_i, e_i)
-    datacost = h·[l_i == e_i]           ('potts', the paper's form)
-             | -h·(l_i - e_i)^2          ('quadratic', gray-level denoising)
+        log P(v) = S(v) + D(v)
+        S = theta·#{q : l_q == v}                ('potts', the paper's form)
+          | -theta·sum_q min((v - l_q)^2, T)     ('truncated_quadratic',
+                                                  T = `truncation`)
+        D = h·[v == e_p]                         ('potts')
+          | -h·(e_p - v)^2                       ('quadratic')
+          | 0 where e_p < 0                      (an unobserved pixel)
+
+    The observation, not the model, carries the mask: each query's image
+    marks its own hole with -1.
+
+    Every implementation (`core.mrf`, `kernels.ref`, the fused kernel)
+    evaluates this in one float32 operation order, so their draws agree
+    bit for bit.  Neighbors are taken in the order up, down, left, right;
+    a missing one (-1 past the grid's edge) contributes 0:
+
+        potts:  n_q = float(l_q == v)
+                S = theta * (((n_up + n_down) + n_left) + n_right)
+        t.q.:   d_q = float(v - l_q);  n_q = min(d_q * d_q, T) or 0.0
+                S = -theta * (((n_up + n_down) + n_left) + n_right)
+        D:      potts h * float(e == v);  quadratic d = float(e - v),
+                (-h * d) * d;  then 0.0 where e < 0
+        log P = S + D
+
+    Integer-valued terms are exact in float32, so only the final
+    products and the sum round.
     """
 
     height: int
@@ -153,6 +182,24 @@ class GridMRF:
     h: float = 2.0
     data_cost: str = "potts"
     name: str = "mrf"
+    pairwise: str = "potts"
+    truncation: float | None = None
+
+    def __post_init__(self):
+        if self.pairwise not in PAIRWISE:
+            raise ValueError(
+                f"pairwise {self.pairwise!r} is not one of {PAIRWISE}")
+        if self.data_cost not in DATA_COSTS:
+            raise ValueError(
+                f"data_cost {self.data_cost!r} is not one of {DATA_COSTS}")
+        if (self.pairwise == "truncated_quadratic") != (
+                self.truncation is not None):
+            # a missing argument, as Python reports one: a truncated
+            # quadratic has no default truncation, and Potts takes none
+            raise TypeError(
+                f"GridMRF(pairwise={self.pairwise!r}) "
+                + ("needs a truncation" if self.truncation is None
+                   else "takes no truncation"))
 
     def checkerboard_colors(self) -> np.ndarray:
         ii = np.add.outer(np.arange(self.height), np.arange(self.width))

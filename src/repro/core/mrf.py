@@ -1,7 +1,7 @@
 """Checkerboard (2-color) chromatic Gibbs for grid MRFs (paper Eqn. 7, Fig. 1f).
 
-The regular-PM counterpart of `bayesnet.py`: a 4-connected Potts/Ising grid
-needs exactly two colors, so one Gibbs iteration is two dense half-steps, each
+The regular-PM counterpart of `bayesnet.py`: a 4-connected grid (Potts or
+truncated-quadratic smoothness, `GridMRF`) needs exactly two colors, so one Gibbs iteration is two dense half-steps, each
 updating every other site simultaneously — AIA's best-case workload (Penguin/
 Art image tasks).  The per-site pipeline is the same C2->C1 chain:
 
@@ -34,29 +34,56 @@ from repro.core.interp import build_exp_weight_lut
 from repro.diag import accum as diag_accum
 
 
-def neighbor_value_counts(labels: jax.Array, n_labels: int) -> jax.Array:
-    """(..., H, W) labels -> (..., H, W, V) count of 4-neighbors per value.
+def neighbor_labels(labels: jax.Array) -> tuple[jax.Array, ...]:
+    """(..., H, W) labels -> the (up, down, left, right) neighbor labels of
+    every site, -1 past the grid's edge (the free boundary)."""
 
-    Border sites see fewer neighbors (zero-padding), matching the free
-    boundary of the benchmark MRFs."""
-    onehot = (
-        labels[..., None] == jnp.arange(n_labels, dtype=labels.dtype)
-    ).astype(jnp.float32)
-
-    def shift(x, d, axis):
-        pad = [(0, 0)] * x.ndim
+    def shift(d, axis):
+        pad = [(0, 0)] * labels.ndim
         pad[axis] = (1, 0) if d > 0 else (0, 1)
-        sl = [slice(None)] * x.ndim
+        sl = [slice(None)] * labels.ndim
         sl[axis] = slice(0, -1) if d > 0 else slice(1, None)
-        return jnp.pad(x[tuple(sl)], pad)
+        return jnp.pad(labels[tuple(sl)], pad, constant_values=-1)
 
     h_ax, w_ax = labels.ndim - 2, labels.ndim - 1
-    return (
-        shift(onehot, 1, h_ax)
-        + shift(onehot, -1, h_ax)
-        + shift(onehot, 1, w_ax)
-        + shift(onehot, -1, w_ax)
-    )
+    return shift(1, h_ax), shift(-1, h_ax), shift(1, w_ax), shift(-1, w_ax)
+
+
+def log_potentials(
+    nbrs, evidence, v, *, theta: float, h: float, data_cost: str = "potts",
+    pairwise: str = "potts", truncation: float | None = None,
+) -> jax.Array:
+    """log P(site = v | neighbors, evidence) in `GridMRF`'s float32
+    operation order, on arrays that broadcast: `nbrs` the (up, down, left,
+    right) neighbor labels (-1 where there is none), `evidence` the
+    observation (< 0 where unobserved), `v` the candidate values.  The one
+    spelling of the energy: the eager engine, the kernel oracle and the
+    fused kernel's tiles all call it."""
+    if pairwise == "potts":
+        n = [(q == v).astype(jnp.float32) for q in nbrs]
+        smooth = theta * (((n[0] + n[1]) + n[2]) + n[3])
+    elif pairwise == "truncated_quadratic":
+        n = []
+        for q in nbrs:
+            d = (v - q).astype(jnp.float32)
+            n.append(jnp.where(q >= 0, jnp.minimum(d * d, truncation), 0.0))
+        smooth = -theta * (((n[0] + n[1]) + n[2]) + n[3])
+    else:
+        raise ValueError(f"pairwise {pairwise!r}")
+    if data_cost == "potts":
+        data = h * (evidence == v).astype(jnp.float32)
+    elif data_cost == "quadratic":
+        diff = (evidence - v).astype(jnp.float32)
+        data = -h * diff * diff
+    else:
+        raise ValueError(f"data_cost {data_cost!r}")
+    return smooth + jnp.where(evidence >= 0, data, 0.0)
+
+
+def energy_fields(mrf: GridMRF) -> dict:
+    """The `log_potentials` keywords of a model."""
+    return dict(theta=mrf.theta, h=mrf.h, data_cost=mrf.data_cost,
+                pairwise=mrf.pairwise, truncation=mrf.truncation)
 
 
 def site_log_potentials(
@@ -65,15 +92,10 @@ def site_log_potentials(
     """Unnormalized log P(site = v | neighbors, evidence) for every site/value.
     labels (..., H, W), evidence (H, W) -> (..., H, W, V)."""
     v_range = jnp.arange(mrf.n_labels, dtype=labels.dtype)
-    smooth = mrf.theta * neighbor_value_counts(labels, mrf.n_labels)
-    if mrf.data_cost == "potts":
-        data = mrf.h * (evidence[..., None] == v_range).astype(jnp.float32)
-    elif mrf.data_cost == "quadratic":
-        diff = (evidence[..., None] - v_range).astype(jnp.float32)
-        data = -mrf.h * diff * diff
-    else:
-        raise ValueError(mrf.data_cost)
-    return smooth + data
+    return log_potentials(
+        [q[..., None] for q in neighbor_labels(labels)],
+        evidence[..., None], v_range, **energy_fields(mrf),
+    )
 
 
 def checkerboard_mask(h: int, w: int, parity: int) -> jax.Array:
@@ -259,16 +281,25 @@ def run_mrf_gibbs(
 
 
 def total_energy(mrf: GridMRF, labels: jax.Array, evidence: jax.Array):
-    """E(l) (paper Eqn. 3/7 numerator, log domain) — test/convergence metric."""
-    onehot_v = jnp.arange(mrf.n_labels, dtype=labels.dtype)
-    right = (labels[..., :, 1:] == labels[..., :, :-1]).astype(jnp.float32)
-    down = (labels[..., 1:, :] == labels[..., :-1, :]).astype(jnp.float32)
-    smooth = mrf.theta * (right.sum((-1, -2)) + down.sum((-1, -2)))
+    """log of the unnormalized joint (paper Eqn. 3/7 numerator): the pair
+    terms once per grid edge plus the data terms of the observed pixels —
+    a test/convergence metric."""
+    pairs = ((labels[..., :, 1:], labels[..., :, :-1]),
+             (labels[..., 1:, :], labels[..., :-1, :]))
+    if mrf.pairwise == "potts":
+        smooth = mrf.theta * sum(
+            (a == b).astype(jnp.float32).sum((-1, -2)) for a, b in pairs)
+    else:
+        d = [(a - b).astype(jnp.float32) for a, b in pairs]
+        smooth = -mrf.theta * sum(
+            jnp.minimum(x * x, mrf.truncation).sum((-1, -2)) for x in d)
+    observed = evidence >= 0
     if mrf.data_cost == "potts":
-        data = mrf.h * (labels == evidence).astype(jnp.float32).sum((-1, -2))
+        data = mrf.h * ((labels == evidence) & observed).astype(
+            jnp.float32).sum((-1, -2))
     else:
         diff = (labels - evidence).astype(jnp.float32)
-        data = -mrf.h * (diff * diff).sum((-1, -2))
+        data = -mrf.h * jnp.where(observed, diff * diff, 0.0).sum((-1, -2))
     return smooth + data
 
 

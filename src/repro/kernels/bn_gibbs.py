@@ -284,7 +284,7 @@ def bn_round_step(
         preprocess_planes(w, precision), words, n_bins=v_max,
         precision=precision, total_steps=total_steps,
     )
-    labels = argmax_fallback(w, label, done)  # (B, c_pad)
+    labels = argmax_fallback(w, label, done, n_bins=v_max)  # (B, c_pad)
 
     # --- in-place scatter: node lanes read their label via the inverse map
     inv = _broadcast_rows(inv_ref, 0, b)

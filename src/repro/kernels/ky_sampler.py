@@ -20,11 +20,13 @@ state, exactly like the paper's asynchronous cores between barriers.
 Layout: the walk works on *planes* — one 2-D array per bin, sites laid out
 lane-dense — so the same `ddg_walk` serves this kernel (batch on lanes, one
 bin per sublane row of the transposed weights), the MRF kernel ((rows, W)
-tiles) and the BN kernel ((chains, nodes) tiles).  Bins are unrolled, so N
-+ rejection bin <= 128 keeps the loop short (wider distributions go through
-token_sampler.py).  The whole working set is VMEM-resident; the distribution
-is produced, walked and discarded without an HBM round-trip — the paper's
-private-RF locality argument.
+tiles) and the BN kernel ((chains, nodes) tiles).  The bins are either a
+list of planes, unrolled (this kernel and the BN kernel, N + rejection bin
+<= 128), or the leading axis of a VMEM ref, walked by a loop over chunks
+of `unroll` bins (the MRF kernel, whose 256 gray levels would otherwise
+unroll into hundreds of live planes).  The whole working set is VMEM-
+resident; the distribution is produced, walked and discarded without an
+HBM round-trip — the paper's private-RF locality argument.
 """
 
 from __future__ import annotations
@@ -52,45 +54,81 @@ def zeros_plane(shape, dtype=jnp.int32) -> jax.Array:
     return z.astype(dtype)
 
 
-def preprocess_planes(w: list, precision: int) -> list:
+def fold_bins(n: int, body, carry, *, unroll: int | None = None):
+    """carry = body(v, carry) for bins v = 0 .. n-1 in order.
+
+    `unroll=None` unrolls every bin (v a Python int: bins held as a list of
+    planes).  Otherwise a loop runs over chunks of `unroll` bins, each chunk
+    unrolled, and the last n % unroll bins follow unrolled (v traced inside
+    the loop: bins on a VMEM ref's leading axis)."""
+    if unroll is None:
+        for v in range(n):
+            carry = body(v, carry)
+        return carry
+    n_loop = n - n % unroll
+
+    def chunk(i, c):
+        for j in range(unroll):
+            c = body(i * unroll + j, c)
+        return c
+
+    if n_loop:
+        carry = jax.lax.fori_loop(0, n_loop // unroll, chunk, carry)
+    for v in range(n_loop, n):
+        carry = body(v, carry)
+    return carry
+
+
+def preprocess_planes(w, precision: int, *, unroll: int | None = None):
     """Clamp -> scale-to-fill -> rejection bin (Eqns. 8-9) on per-bin planes.
 
-    `w` holds one int32 plane per bin, all of one shape (any sites layout);
-    returns len(w) + 1 planes summing to 2^precision, the rejection bin
-    last.  Integer-only, so any summation order is exact."""
-    m = [jnp.maximum(x, 0) for x in w]
-    empty = _plane_sum(m) <= 0
-    m = [jnp.where(empty, 1, x) for x in m]  # all-zero row -> uniform
+    `w` holds one int32 plane per bin, all of one shape (any sites layout):
+    a list, copied and returned with the rejection bin appended, or, with
+    `unroll`, a VMEM ref of n + 1 planes whose first n hold the weights,
+    scaled in place, the rejection bin written to the last and the ref
+    returned (`fold_bins`' loop).  The n + 1 planes sum to 2^precision.
+    Integer-only, so any summation order is exact."""
+    m, n = ([*w, None], len(w)) if unroll is None else (w, w.shape[0] - 1)
+
+    def clamped(v):
+        return jnp.maximum(m[v], 0)
+
+    total = fold_bins(n - 1, lambda v, acc: acc + clamped(v + 1), clamped(0),
+                      unroll=unroll)
+    empty = total <= 0  # all-zero site -> uniform: n ones
     # (2^W - 1) // S, as core.ky.scale_to_fill: no bin may reach 2^W
-    k = jnp.maximum(((1 << precision) - 1) // _plane_sum(m), 1)
-    m = [x * k for x in m]
-    return m + [(1 << precision) - _plane_sum(m)]
+    k = jnp.maximum(((1 << precision) - 1) // jnp.where(empty, n, total), 1)
 
+    def scaled(v):
+        x = jnp.where(empty, 1, clamped(v)) * k
+        m[v] = x
+        return x
 
-def _plane_sum(planes: list) -> jax.Array:
-    acc = planes[0]
-    for x in planes[1:]:
-        acc = acc + x
-    return acc
+    first = scaled(0)
+    m[n] = (1 << precision) - fold_bins(
+        n - 1, lambda v, acc: acc + scaled(v + 1), first, unroll=unroll)
+    return m
 
 
 def ddg_walk(
-    m_ext: list, words: list, *, n_bins: int, precision: int,
-    total_steps: int,
+    m_ext, words: list, *, n_bins: int, precision: int,
+    total_steps: int, unroll: int | None = None,
 ):
     """Early-exit batched DDG walk on per-bin planes.
 
     `m_ext` is `preprocess_planes`' output (n_bins + 1 int32 planes, the
-    rejection bin last) and `words` the n_words uint32 planes of packed
-    random bits, all of one 2-D sites shape.  Each site walks its own tree:
-    one bit per level, the running sum over the bin planes plays the
-    parallel-prefix adder, and the first bin whose prefix exceeds the
-    distance terminates.  Returns (labels, bits, rejs, done) planes; labels
-    is -1 where the bit budget ran out (`argmax_fallback` fills those).
+    rejection bin last: a list, or with `unroll` a VMEM ref) and `words`
+    the n_words uint32 planes of packed random bits, all of one 2-D sites
+    shape.  Each site walks its own tree: one bit per level, the running
+    sum over the bin planes plays the parallel-prefix adder, and the first
+    bin whose prefix exceeds the distance terminates.  Returns (labels,
+    bits, rejs, done) planes; labels is -1 where the bit budget ran out
+    (`argmax_fallback` fills those).
 
-    Sites stay lane-dense and bins are unrolled, so the walk lowers with
-    Mosaic: no lane-axis dynamic slice, no (b, 1) state, no matmul."""
-    zi = zeros_plane(m_ext[0].shape)
+    Sites stay lane-dense and the bins are unrolled or looped over a ref's
+    leading axis, so the walk lowers with Mosaic: no lane-axis dynamic
+    slice, no (b, 1) state, no matmul."""
+    zi = zeros_plane(words[0].shape)
     one = jnp.asarray(1, words[0].dtype)
 
     # `done` rides the loop as an int32 0/1 plane: Mosaic cannot carry a
@@ -109,10 +147,13 @@ def ddg_walk(
         active = done == 0
         d = jnp.where(active, 2 * d + bit, d)
         col_shift = (precision - 1) - level
-        c, idx = zi, zi
-        for m in m_ext:
-            c = c + ((m >> col_shift) & 1)
-            idx = idx + (c <= d).astype(jnp.int32)  # first bin with c > d
+
+        def prefix(v, acc):
+            c, idx = acc
+            c = c + ((m_ext[v] >> col_shift) & 1)
+            return c, idx + (c <= d).astype(jnp.int32)  # first bin, c > d
+
+        c, idx = fold_bins(n_bins + 1, prefix, (zi, zi), unroll=unroll)
         terminated = active & (c > d)
         is_rej = idx >= n_bins
         accept = terminated & ~is_rej
@@ -135,14 +176,21 @@ def ddg_walk(
     return label, bits, rejs, done
 
 
-def argmax_fallback(w: list, labels: jax.Array, done: jax.Array) -> jax.Array:
-    """Fallback for the (<2^-max_retries) bit-exhaustion case: the first bin
-    of largest raw weight (`jnp.argmax` semantics), on per-bin planes."""
-    best, amax = w[0], jnp.zeros(w[0].shape, jnp.int32)
-    for v in range(1, len(w)):
-        better = w[v] > best
-        best = jnp.where(better, w[v], best)
-        amax = jnp.where(better, v, amax)
+def argmax_fallback(w, labels: jax.Array, done: jax.Array, *, n_bins: int,
+                    unroll: int | None = None) -> jax.Array:
+    """Fallback for the (<2^-max_retries) bit-exhaustion case: the first of
+    bins 0 .. n_bins - 1 of largest weight (`jnp.argmax` semantics), on
+    per-bin planes (a list, or with `unroll` a ref).  The weights may be
+    `preprocess_planes`' scaled ones: for weights >= 0, one positive factor
+    per site keeps the first largest bin where it was."""
+    def pick(v, acc):
+        best, amax = acc
+        x = w[v + 1]
+        better = x > best
+        return jnp.where(better, x, best), jnp.where(better, v + 1, amax)
+
+    _, amax = fold_bins(n_bins - 1, pick, (w[0], zeros_plane(labels.shape)),
+                        unroll=unroll)
     return jnp.where(done, labels, amax)
 
 
@@ -157,7 +205,7 @@ def _ky_kernel(
         preprocess_planes(w, precision), words, n_bins=n_bins,
         precision=precision, total_steps=total_steps,
     )
-    labels_ref[...] = argmax_fallback(w, label, done)
+    labels_ref[...] = argmax_fallback(w, label, done, n_bins=n_bins)
     bits_ref[...] = bits
     rej_ref[...] = rejs
     fb_ref[...] = (~done).astype(jnp.int32)

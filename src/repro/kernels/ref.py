@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import ky as ky_core
+from repro.core import mrf as mrf_mod
 from repro.core.interp import LUTSpec, interp_ref
 
 
@@ -50,45 +51,31 @@ def mrf_gibbs_half_step(
     exp_table: jax.Array,
     exp_spec: LUTSpec,
     data_cost: str = "potts",
+    pairwise: str = "potts",
+    truncation: float | None = None,
     precision: int = 16,
     max_retries: int = 8,
 ) -> jax.Array:
     """Oracle for kernels.mrf_gibbs: one checkerboard half-step of chromatic
-    Gibbs on a Potts/Ising grid MRF (paper Eqn. 7, Alg. 2 with K=2 colors).
+    Gibbs on a grid MRF (paper Eqn. 7, Alg. 2 with K=2 colors).
 
-    labels, evidence: (H, W) int32 in [0, n_labels); words: (H, W, n_words)
-    uint32; parity selects the color (checkerboard) being updated.
+    labels, evidence: (H, W) int32, labels in [0, n_labels), evidence < 0
+    where unobserved; words: (H, W, n_words) uint32; parity selects the
+    color (checkerboard) being updated.
 
-    Energy of assigning value v at site (i,j):
-        E(v) = theta * sum_{nbr} [v == label_nbr] + datacost(v, e_ij)
-    P(v) ∝ exp(E(v)); exp is evaluated through the integer-weight LUT (C2)
-    and the draw uses rejection-KY (C1) — normalization-free end to end.
-    Op-for-op identical to the fused kernel so equality is exact.
+    The energy of assigning value v at site (i,j) is `GridMRF`'s, in its
+    float32 operation order (`core.mrf.log_potentials`).  P(v) ∝ exp(E(v));
+    exp is evaluated through the integer-weight LUT (C2) and the draw uses
+    rejection-KY (C1) — normalization-free end to end.  Op-for-op identical
+    to the fused kernel so equality is exact.
     """
     hh, ww = labels.shape
-
-    def nbr(shift, axis):
-        rolled = jnp.roll(labels, shift, axis=axis)
-        # out-of-grid neighbors marked -1 (no value matches)
-        idx = jnp.arange(labels.shape[axis])
-        edge = idx == (0 if shift == 1 else labels.shape[axis] - 1)
-        edge = jnp.expand_dims(edge, axis=1 - axis)
-        return jnp.where(edge, -1, rolled)
-
-    up, down, left, right = nbr(1, 0), nbr(-1, 0), nbr(1, 1), nbr(-1, 1)
-    energies = []
-    for v in range(n_labels):
-        cnt = (
-            ((up == v).astype(jnp.float32) + (down == v).astype(jnp.float32))
-            + (left == v).astype(jnp.float32)
-        ) + (right == v).astype(jnp.float32)
-        if data_cost == "potts":
-            data = h * (evidence == v).astype(jnp.float32)
-        else:
-            diff = (evidence - v).astype(jnp.float32)
-            data = -h * diff * diff
-        energies.append(theta * cnt + data)
-    e = jnp.stack(energies, axis=-1)  # (H, W, V)
+    e = mrf_mod.log_potentials(
+        [q[..., None] for q in mrf_mod.neighbor_labels(labels)],
+        evidence[..., None], jnp.arange(n_labels, dtype=labels.dtype),
+        theta=theta, h=h, data_cost=data_cost, pairwise=pairwise,
+        truncation=truncation,
+    )  # (H, W, V)
     z = e - e.max(axis=-1, keepdims=True)
     tab = jnp.ravel(exp_table)[: exp_spec.size]
     w_int = jnp.maximum(jnp.round(interp_ref(z, tab, exp_spec)), 0.0)

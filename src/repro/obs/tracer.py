@@ -245,16 +245,29 @@ def span(name: str, cat: str = "host", track: str | None = None, **args):
     return _Span(t, name, cat, track, args)
 
 
+def live() -> bool:
+    """Whether a span or a timeline instant would record anywhere: the
+    ring buffer is on or a `jax.profiler` trace records."""
+    return _TRACER is not None or profiling()
+
+
 def instant(
     name: str, cat: str = "host", track: str | None = None,
-    sim_t: float | None = None, wargs: dict | None = None, **args,
+    sim_t: float | None = None, wargs: dict | None = None,
+    timeline: bool = False, **args,
 ) -> None:
-    """A point event (shed/defer decisions, flush markers, round costs)."""
+    """A point event (shed/defer decisions, flush markers, round costs).
+    `timeline=True` also puts it, as a zero-length annotation, on the
+    profiler's host timeline while a trace records."""
     t = _TRACER
-    if t is None:
+    if t is None and not (timeline and profiling()):
         return
-    t.emit("instant", name, cat, track, sim_t0=sim_t, sim_t1=sim_t,
-           args=args, wargs=wargs)
+    if t is not None:
+        t.emit("instant", name, cat, track, sim_t0=sim_t, sim_t1=sim_t,
+               args=args, wargs=wargs)
+    if timeline and profiling():
+        with TraceAnnotation(name, **args):
+            pass
 
 
 def sim_span(

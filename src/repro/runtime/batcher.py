@@ -89,7 +89,7 @@ class Query:
     qid: int
     model: str
     evidence: dict | None = None  # BN: {node: value} clamps; MRF: pins
-    image: np.ndarray | None = None  # MRF observation image (H, W)
+    image: np.ndarray | None = None  # MRF observation (H, W); -1 unobserved
     n_chains: int = 8
     n_iters: int = 40
     burn_in: int = 10  # BN marginal accumulation only; ignored for MRF

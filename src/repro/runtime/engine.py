@@ -38,6 +38,7 @@ import dataclasses
 import heapq
 import time
 
+from repro.analysis import kernel_lint
 from repro.compile import compile_graph, set_cache_capacity
 from repro.compile import ir as ir_mod
 from repro.core.graphs import DiscreteBayesNet, GridMRF
@@ -143,6 +144,9 @@ class Engine:
         # bucket dispatches over the engine's life: the `dispatch` number
         # every wall span of one dispatch carries
         self._dispatches = 0
+        # (model, chains, sampler, shard width) demotions already reported
+        # in this run or calibration (`_note_demotion`)
+        self._demoted: set = set()
 
     # -- admission ---------------------------------------------------------
 
@@ -198,11 +202,33 @@ class Engine:
         return 1
 
     def _bucket_key(self, q: Query) -> BucketKey:
-        return batcher_mod.bucket_key(
+        width = self._shard_width_of(q)
+        key = batcher_mod.bucket_key(
             q, self.graphs[q.model], self.config.backend,
             self.config.slice_iters, fused=self.config.fused,
-            diagnostics=self.config.diagnostics,
-            shard_width=self._shard_width_of(q),
+            diagnostics=self.config.diagnostics, shard_width=width,
+        )
+        if self.config.fused and not key.fused and tracer.live():
+            self._note_demotion(q, key, width)
+        return key
+
+    def _note_demotion(self, q: Query, key: BucketKey, width: int) -> None:
+        """A `route/demoted` instant, once per (model, chains, sampler,
+        shard width) in a `run` or `calibrate`, when the VMEM estimate
+        sends a bucket that asked for the fused route to the XLA one."""
+        graph = self.graphs[q.model]
+        seen = (q.model, key.n_chains, key.sampler, width)
+        if seen in self._demoted or not batcher_mod.fused_eligible(
+                key.kind, key.sampler, key.backend):
+            return
+        self._demoted.add(seen)
+        fp = kernel_lint.estimate_footprint(graph, key.n_chains, key.sampler,
+                                            width)
+        tracer.instant(
+            "route/demoted", cat="route", timeline=True, model=q.model,
+            kind=key.kind, n_chains=key.n_chains, sampler=key.sampler,
+            shard_width=width, estimate_bytes=fp.total_bytes,
+            budget_bytes=kernel_lint.vmem_budget(),
         )
 
     def _make_calibrator(self) -> Calibrator:
@@ -226,6 +252,7 @@ class Engine:
         cfg = self.config
         if self.calibrator is None:
             self.calibrator = self._make_calibrator()
+        self._demoted = set()
         qs = list(self._queue if queries is None else queries)
         buckets: dict[BucketKey, list[Query]] = {}
         for q in sorted(qs, key=lambda q: (q.arrival_s, q.qid)):
@@ -282,6 +309,7 @@ class Engine:
         # wall-metric half of the dual clock, not the sim's event time
         wall0 = time.perf_counter()  # lint: allow[wallclock-in-sim]
         self.metrics = RuntimeMetrics()  # run-scoped cache delta
+        self._demoted = set()
         executor = Executor(
             ExecutorConfig(
                 n_workers=cfg.n_workers, shard_width=cfg.shard_width,
@@ -457,6 +485,10 @@ class Engine:
                         pad_efficiency=round(len(qs) / n_padded, 6),
                         n_iters=key.n_iters, n_chains=key.n_chains,
                     )
+                    if key.kind == "mrf":
+                        mrf = self.graphs[qs[0].model].source
+                        span.set(n_labels=mrf.n_labels,
+                                 pairwise=mrf.pairwise)
                 batch, measured_s, lowered = executor.timed_execute(
                     program, key, qs, route, return_state
                 )

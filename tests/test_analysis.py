@@ -338,6 +338,23 @@ def test_bn_footprint_scales_with_chains_mrf_does_not():
     assert a.total_bytes <= kernel_lint.vmem_budget()
 
 
+def test_penguin_fits_the_fused_budget():
+    """The served Penguin bucket (179 x 122, 256 labels, 8 chains) routes
+    fused: the planar estimate counts the kernel's tile rows, W padded to
+    128 lanes, one energy and one weight plane per label — no 128-lane
+    row per site."""
+    from repro.kernels import mrf_tiles
+
+    mrf = GridMRF(179, 122, 256, theta=1.0, h=0.04, data_cost="quadratic",
+                  pairwise="truncated_quadratic", truncation=200,
+                  name="penguin")
+    fp = kernel_lint.mrf_fused_footprint(compile_ir.from_mrf(mrf), 8)
+    rows = mrf_tiles.tile_rows(179, 122, 256)
+    assert fp.breakdown["label_planes"] == 513 * rows * 128 * 4
+    assert fp.total_bytes <= kernel_lint.vmem_budget()
+    assert kernel_lint.fused_fits(compile_ir.from_mrf(mrf), 8)
+
+
 def test_footprint_findings_severity():
     pigs = _bn_ir("pigs")
     fp = kernel_lint.bn_fused_footprint(pigs, 256)

@@ -141,3 +141,60 @@ def test_fused_shard_route_boundary_8dev():
     )
     assert res.returncode == 0, res.stderr[-3000:]
     assert "SHARD_BOUNDARY_OK" in res.stdout
+
+
+_TQ_SCRIPT = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import numpy as np, jax, jax.numpy as jnp
+
+    from repro.compile import compile_graph
+    from repro.compile import ir as compile_ir
+    from repro.core import compat
+    from repro.core.distributed import mrf_gibbs_sharded
+    from repro.core.graphs import GridMRF
+
+    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    # 20 rows over 4 shards: 5-row slabs, each padded to an 8-row tile
+    # whose first padded row is the neighbor shard's halo
+    mrf = GridMRF(20, 16, 8, theta=0.8, h=0.1, data_cost="quadratic",
+                  pairwise="truncated_quadratic", truncation=9.0)
+    prog = compile_graph(compile_ir.from_mrf(mrf))
+    rng = np.random.default_rng(0)
+    ev = rng.integers(0, 8, (20, 16))
+    ev = jnp.asarray(np.where(rng.random((20, 16)) < 0.2, -1, ev), jnp.int32)
+    key = jax.random.key(7)
+    run = dict(evidence=ev, n_chains=4, n_iters=2)
+    sharded = prog.run_sharded(key, mesh, fused=True, **run)
+    single = prog.run(key, fused=True, **run)
+    unfused = prog.run(key, **run)
+    assert (np.asarray(sharded) == np.asarray(single)).all()
+    assert (np.asarray(single) == np.asarray(unfused)).all()
+    print("TQ_SHARDED_FUSED_OK")
+    # the legacy sharded engine computes Potts only, and says so
+    try:
+        mrf_gibbs_sharded(mrf, ev, key, mesh, n_chains=4, n_iters=1)
+    except ValueError as e:
+        assert "pairwise" in str(e), e
+        print("LEGACY_REFUSES_TQ_OK")
+    """
+)
+
+
+@pytest.mark.slow
+def test_fused_sharded_truncated_quadratic_8dev():
+    """A truncated-quadratic grid with masked pixels: the sharded fused
+    route (row slabs padded to whole tiles) equals one device's fused
+    route and the unfused route bit for bit, and the legacy Potts-only
+    sharded engine refuses the model."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(ROOT / "src")
+    env.pop("XLA_FLAGS", None)
+    res = subprocess.run(
+        [sys.executable, "-c", _TQ_SCRIPT], env=env, capture_output=True,
+        text=True, timeout=900,
+    )
+    assert res.returncode == 0, res.stderr[-3000:]
+    assert "TQ_SHARDED_FUSED_OK" in res.stdout
+    assert "LEGACY_REFUSES_TQ_OK" in res.stdout

@@ -172,3 +172,105 @@ def test_profiler_only_spans_are_live_and_leave_no_ring_events(tmp_path):
     probes = [e for e in _host_events(str(tmp_path)) if e[1] == "probe"]
     assert len(probes) == 1
     assert probes[0][4] == {"dispatch": 3, "extra": 5}
+
+
+def _mrf_engine(**config):
+    from repro.core.graphs import GridMRF
+
+    mrf = GridMRF(6, 5, 12, theta=0.5, h=0.1, data_cost="quadratic",
+                  pairwise="truncated_quadratic", truncation=9.0)
+    return Engine({"grid": mrf}, EngineConfig(pad_sizes=(2,), max_batch=2,
+                                              **config))
+
+
+def test_mrf_dispatch_span_carries_labels_and_pairwise():
+    import numpy as np
+
+    engine = _mrf_engine()
+    tr = obs.enable()
+    image = np.where(np.arange(30).reshape(6, 5) % 4 == 0, -1, 3)
+    engine.submit([Query(qid=0, model="grid", image=image.astype(np.int32),
+                         n_chains=2, n_iters=2, seed=1)])
+    engine.run()
+    obs.disable()
+    (span,) = [e for e in tr.events if e.name == "engine/dispatch"]
+    assert span.args["n_labels"] == 12
+    assert span.args["pairwise"] == "truncated_quadratic"
+
+
+def _demotion_queries():
+    """Three (model, chains) keys; survey's two-chain key is asked for by
+    two queries and re-enters as slice continuations."""
+    return [
+        Query(qid=0, model="survey", evidence={0: 1}, n_chains=2, n_iters=8,
+              burn_in=0, seed=1),
+        Query(qid=1, model="survey", evidence={0: 0}, n_chains=2, n_iters=8,
+              burn_in=0, seed=2),
+        Query(qid=2, model="survey", evidence={0: 0}, n_chains=4, n_iters=4,
+              burn_in=0, seed=3),
+        Query(qid=3, model="cancer", evidence={0: 0}, n_chains=2, n_iters=4,
+              burn_in=0, seed=4, arrival_s=1e-4),
+    ]
+
+
+def _demoting_engine():
+    models = {"survey": bn_repository_replica("survey"),
+              "cancer": bn_repository_replica("cancer")}
+    return Engine(models, EngineConfig(pad_sizes=(2,), max_batch=2,
+                                       slice_iters=4, fused=True))
+
+
+@pytest.fixture
+def tiny_budget():
+    from repro.analysis import kernel_lint
+
+    prev = kernel_lint.set_vmem_budget(1 << 10)
+    yield 1 << 10
+    kernel_lint.set_vmem_budget(prev)
+
+
+def test_forced_demotion_emits_one_route_demoted_per_key(tiny_budget,
+                                                         tmp_path):
+    engine = _demoting_engine()
+    tr = obs.enable()
+    engine.submit(_demotion_queries())
+    engine.run()
+    obs.disable()
+    dispatches = [e for e in tr.events if e.name == "engine/dispatch"]
+    assert dispatches and not any(e.args["fused"] for e in dispatches)
+    demoted = [e.args for e in tr.events if e.name == "route/demoted"]
+    keys = sorted((a["model"], a["n_chains"], a["sampler"], a["shard_width"])
+                  for a in demoted)
+    assert keys == [("cancer", 2, "lut_ky", 1), ("survey", 2, "lut_ky", 1),
+                    ("survey", 4, "lut_ky", 1)]
+    for a in demoted:
+        assert a["budget_bytes"] == tiny_budget < a["estimate_bytes"]
+    # on the profiler's host timeline too, once per key in each run
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        engine.submit(_demotion_queries())
+        engine.run()
+    finally:
+        jax.profiler.stop_trace()
+    marks = [e for e in _host_events(str(tmp_path))
+             if e[1] == "route/demoted"]
+    assert sorted((e[4]["model"], e[4]["n_chains"]) for e in marks) == [
+        ("cancer", 2), ("survey", 2), ("survey", 4)]
+
+
+def test_demotion_site_does_nothing_with_tracing_off(tiny_budget,
+                                                     monkeypatch):
+    engine = _demoting_engine()
+    assert not tracer.enabled() and not tracer.profiling()
+
+    def note(*a, **k):
+        raise AssertionError("a demotion noted with tracing off")
+
+    instants = []
+    monkeypatch.setattr(engine, "_note_demotion", note)
+    monkeypatch.setattr(tracer, "instant",
+                        lambda name, *a, **k: instants.append(name))
+    engine.submit(_demotion_queries())
+    engine.run()
+    assert not engine._bucket_key(_demotion_queries()[0]).fused
+    assert "route/demoted" not in instants

@@ -215,6 +215,77 @@ def test_bn_footprint_bounds_mosaic_on_served_buckets(one_chip, monkeypatch,
     assert MOSAIC_CALL in text
 
 
+def _penguin_graph():
+    """The served `penguin.inpaint` model and its structure-only IR."""
+    import json
+    import pathlib
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from bench import harness, models as bench_models
+    from repro.compile import ir as ir_mod
+
+    config = json.loads(
+        (root / "bench/configs/middlebury-penguin.json").read_text())
+    mrf = harness.program_models(
+        config, bench_models.build(config))["penguin"]
+    return mrf, ir_mod.canonicalize(mrf, evidence_mode="runtime")
+
+
+def _penguin_bucket(one_chip, monkeypatch, limit) -> str:
+    """Compile the served `penguin.inpaint` bucket (4 query lanes x 8
+    chains, one 50-sweep slice, 256 labels on 179 x 122) with the MRF
+    kernel's scoped VMEM limit set to `limit`."""
+    from repro.core import compat
+    from repro.runtime import batcher
+
+    mrf, graph = _penguin_graph()
+    n_chains, lanes = 8, 4
+    query = batcher.Query(qid=0, model="penguin", n_chains=n_chains)
+    key = batcher.bucket_key(query, graph, "schedule", fused=True)
+    assert key.fused  # Penguin at 8 chains fits the 16 MiB budget
+    params, seen = compat.tpu_compiler_params, []
+
+    def limited(**kw):
+        seen.append(limit)
+        return params(vmem_limit_bytes=int(limit), **kw)
+
+    monkeypatch.setattr(compat, "tpu_compiler_params", limited)
+    jax.clear_caches()  # trace the kernel anew, under this limit
+    shape = (lanes, mrf.height, mrf.width)
+    text = batcher._mrf_bucket.lower(
+        mrf, (0, 1), _on(one_chip, shape, jnp.int32),
+        _on(one_chip, (lanes,), jnp.uint32), None, None, None, None,
+        n_chains=n_chains, n_iters=50, sampler="lut_ky", fused=True,
+        interpret=False, eager=False, return_state=True,
+    ).compile().as_text()
+    assert seen  # the kernel was traced under the limit
+    return text
+
+
+def test_mrf_footprint_bounds_mosaic_on_the_served_penguin_bucket(
+        one_chip, monkeypatch):
+    """`kernel_lint.mrf_fused_footprint` bounds what Mosaic allocates for
+    the served Penguin bucket, and is no loose bound: the bucket compiles
+    with the kernel's scoped VMEM limit at the estimate, and not at 0.4
+    of it (the per-label scratch alone is three quarters of the
+    estimate)."""
+    from repro.analysis import kernel_lint
+    from repro.kernels.mrf_gibbs import KERNEL_NAME
+
+    limit = kernel_lint.mrf_fused_footprint(_penguin_graph()[1],
+                                            8).total_bytes
+    text = _penguin_bucket(one_chip, monkeypatch, limit)
+    assert MOSAIC_CALL in text
+    calls = re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = [^\n]* custom-call\(",
+                       text, re.M)
+    assert any(c.startswith(KERNEL_NAME) for c in calls), calls
+    with pytest.raises(Exception, match="(?i)vmem|memory|scoped"):
+        _penguin_bucket(one_chip, monkeypatch, 0.4 * limit)
+
+
 def test_mrf_fused_sharded_compiles(topo):
     """The one-shard_map-body MRF program on a 2x2 mesh of described chips:
     halo ppermutes between Mosaic half-steps over each device's row slab."""
